@@ -1,13 +1,12 @@
 import os
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 """Expert-parallel MoE dispatch benchmark (kernels/moe/ep, DESIGN.md §10).
 
-Forces 8 CPU host devices and sweeps EP ∈ {1, 2, 4} on the reduced MoE
-config (mesh (1, EP, 8/EP) over data x expert x model, so tokens shard over
-exactly the EP axis and the leftover devices exercise the expert-ffn TP
-path), writing BENCH_moe_ep.json.  Four gates, all hard-failed:
+Gives the CPU backend 8 host devices (a TPU host keeps its own) and sweeps
+EP ∈ {1, 2, 4} on the reduced MoE config (mesh (1, EP, 8/EP) over
+data x expert x model, so tokens shard over exactly the EP axis and the
+leftover devices exercise the expert-ffn TP path), writing BENCH_moe_ep.json.  Four gates, all hard-failed:
 
   * parity: the EP path matches the single-device dense oracle
     (moe_apply_oracle) forward to < 1e-4 at every EP degree;

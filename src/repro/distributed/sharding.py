@@ -58,33 +58,6 @@ HSDP: bool = False
 SERVE_TP_ONLY: bool = False
 
 
-def use_mesh(mesh: Mesh):
-    """Version-compatible "enter this mesh" context manager.
-
-    ``jax.set_mesh`` only exists in newer JAX; older releases spell it
-    ``jax.sharding.use_mesh``, and before that a ``Mesh`` was itself the
-    context manager.  All three enable named-axis resolution for
-    ``with_sharding_constraint`` / jitted sharding inside the block.
-    """
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    if hasattr(jax.sharding, "use_mesh"):
-        return jax.sharding.use_mesh(mesh)
-    return mesh
-
-
-def jit_shardings(tree, mesh: Mesh):
-    """Adapt a PartitionSpec tree for jit's in/out_shardings.  Modern JAX
-    accepts raw specs inside a ``use_mesh`` scope; older releases require
-    concrete ``NamedSharding``s — wrap the leaves there (None passes through
-    as "infer")."""
-    if hasattr(jax, "set_mesh") or hasattr(jax.sharding, "use_mesh"):
-        return tree
-    return jax.tree_util.tree_map(
-        lambda s: NamedSharding(mesh, s), tree,
-        is_leaf=lambda x: isinstance(x, P))
-
-
 def fsdp_axes(mesh: Mesh) -> Tuple[str, ...]:
     """ZeRO-3 parameter-sharding axes.  The "expert" axis (carved out of
     the data dimension, DESIGN.md §10) participates: its device groups are

@@ -99,7 +99,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
-        lse_ref[0] = (m_scr[...] + jnp.log(l))[:, 0]
+        lse_ref[0] = m_scr[...] + jnp.log(l)
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
@@ -107,13 +107,15 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     softcap: Optional[float] = None,
                     scale: Optional[float] = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True,
+                    interpret: bool,
                     return_lse: bool = False):
     """q: (B,H,Sq,hd), k/v: (B,KV,Skv,hd) -> (B,H,Sq,hd).  GQA via H % KV == 0.
 
     ``scale`` defaults to hd**-0.5 (pass the pre-padding value when padding).
     ``return_lse`` additionally returns the per-row logsumexp (B,H,Sq) f32 —
-    the backward-pass residual."""
+    the backward-pass residual.  The kernel carries it as (B*H, Sq, 1) so its
+    (bq, 1) blocks meet the TPU (8, 128) block rule (a trailing dim equal to
+    the array's passes)."""
     B, H, Sq, hd = q.shape
     KV, Skv = k.shape[1], k.shape[2]
     assert H % KV == 0
@@ -140,11 +142,11 @@ def flash_attention(q, k, v, *, causal: bool = True,
         ],
         out_specs=[
             pl.BlockSpec((1, bq, hd), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bq), lambda bh, i, j: (bh, i)),
+            pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Sq, hd), q.dtype),
-            jax.ShapeDtypeStruct((B * H, Sq), jnp.float32),
+            jax.ShapeDtypeStruct((B * H, Sq, 1), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((bq, 1), jnp.float32),
@@ -209,8 +211,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0][:, None]
-        delta = delta_ref[0][:, None]
+        lse = lse_ref[0]                                # (bq, 1)
+        delta = delta_ref[0]
         _, ds = _p_ds_tiles(q, k, v, do, lse, delta, iq, jk, scale=scale,
                             causal=causal, window=window, softcap=softcap,
                             bq=bq, bk=bk)
@@ -242,8 +244,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0].astype(jnp.float32)
         v = v_ref[0].astype(jnp.float32)
         do = do_ref[0, 0].astype(jnp.float32)
-        lse = lse_ref[0, 0][:, None]
-        delta = delta_ref[0, 0][:, None]
+        lse = lse_ref[0, 0]                             # (bq, 1)
+        delta = delta_ref[0, 0]
         p, ds = _p_ds_tiles(q, k, v, do, lse, delta, iq, jk, scale=scale,
                             causal=causal, window=window, softcap=softcap,
                             bq=bq, bk=bk)
@@ -265,12 +267,13 @@ def flash_attention_bwd(q, k, v, lse, delta, do, *, causal: bool = True,
                         softcap: Optional[float] = None,
                         scale: Optional[float] = None,
                         block_q: int = 128, block_k: int = 128,
-                        interpret: bool = True):
+                        interpret: bool):
     """Pallas flash-attention backward from O(S) residuals.
 
     q/do: (B,H,Sq,hd), k/v: (B,KV,Skv,hd), lse/delta: (B,H,Sq) f32 with
     delta = rowsum(dO * O).  Returns (dq, dk, dv) — dk/dv group-reduced to
-    (B,KV,Skv,hd)."""
+    (B,KV,Skv,hd).  lse/delta ride as (..., Sq, 1) columns, like the forward's
+    lse output."""
     B, H, Sq, hd = q.shape
     KV, Skv = k.shape[1], k.shape[2]
     assert H % KV == 0
@@ -284,8 +287,8 @@ def flash_attention_bwd(q, k, v, lse, delta, do, *, causal: bool = True,
     kr = k.reshape(B * KV, Skv, hd)
     vr = v.reshape(B * KV, Skv, hd)
     dor = do.reshape(B * H, Sq, hd)
-    lser = lse.reshape(B * H, Sq).astype(jnp.float32)
-    deltar = delta.reshape(B * H, Sq).astype(jnp.float32)
+    lser = lse.reshape(B * H, Sq, 1).astype(jnp.float32)
+    deltar = delta.reshape(B * H, Sq, 1).astype(jnp.float32)
 
     dq_kern = functools.partial(
         _bwd_dq_kernel, scale=scale, causal=causal, window=window,
@@ -298,8 +301,8 @@ def flash_attention_bwd(q, k, v, lse, delta, do, *, causal: bool = True,
             pl.BlockSpec((1, bk, hd), lambda bh, i, j: (bh // G, j, 0)),
             pl.BlockSpec((1, bk, hd), lambda bh, i, j: (bh // G, j, 0)),
             pl.BlockSpec((1, bq, hd), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bq), lambda bh, i, j: (bh, i)),
-            pl.BlockSpec((1, bq), lambda bh, i, j: (bh, i)),
+            pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0)),
         ],
         out_specs=pl.BlockSpec((1, bq, hd), lambda bh, i, j: (bh, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, hd), q.dtype),
@@ -310,8 +313,8 @@ def flash_attention_bwd(q, k, v, lse, delta, do, *, causal: bool = True,
     # group-major layouts so the dk/dv grid walks (g, iq) innermost
     qg = qr.reshape(B * KV, G, Sq, hd)
     dog = dor.reshape(B * KV, G, Sq, hd)
-    lseg = lser.reshape(B * KV, G, Sq)
-    deltag = deltar.reshape(B * KV, G, Sq)
+    lseg = lser.reshape(B * KV, G, Sq, 1)
+    deltag = deltar.reshape(B * KV, G, Sq, 1)
 
     dkv_kern = functools.partial(
         _bwd_dkv_kernel, scale=scale, causal=causal, window=window,
@@ -326,8 +329,10 @@ def flash_attention_bwd(q, k, v, lse, delta, do, *, causal: bool = True,
             pl.BlockSpec((1, bk, hd), lambda b, j, t: (b, j, 0)),
             pl.BlockSpec((1, 1, bq, hd),
                          lambda b, j, t: (b, t // nq, t % nq, 0)),
-            pl.BlockSpec((1, 1, bq), lambda b, j, t: (b, t // nq, t % nq)),
-            pl.BlockSpec((1, 1, bq), lambda b, j, t: (b, t // nq, t % nq)),
+            pl.BlockSpec((1, 1, bq, 1),
+                         lambda b, j, t: (b, t // nq, t % nq, 0)),
+            pl.BlockSpec((1, 1, bq, 1),
+                         lambda b, j, t: (b, t // nq, t % nq, 0)),
         ],
         out_specs=[
             pl.BlockSpec((1, bk, hd), lambda b, j, t: (b, j, 0)),

@@ -58,7 +58,7 @@ def _kernel(x_ref, b_ref, c_ref, dt_ref, la_ref, o_ref, s_scr, *, T: int):
 
 
 def mamba_ssd(x, B_t, C_t, dt, log_a, *, chunk: int = 128,
-              interpret: bool = True):
+              interpret: bool):
     """x: (B,H,S,P); B_t/C_t: (B,S,N); dt/log_a: (B,H,S).  Returns y like x."""
     Bb, H, S, P = x.shape
     N = B_t.shape[-1]

@@ -11,9 +11,8 @@ shard over the same axis for dispatch.  Inside a ``shard_map`` each device:
   2. exchanges the buffers with ``jax.lax.all_to_all`` — a *ragged* exchange
      emulated over a static-capacity layout: per-peer send counts come from
      the pack plan, live rows sit at the front of each peer block, and the
-     tail is zero padding (this JAX has no ``lax.ragged_all_to_all``; on
-     newer releases the identical counts/layout drive the real ragged op,
-     shrinking the wire bytes to the counts);
+     tail is zero padding (``lax.ragged_all_to_all`` could take the same
+     counts/layout and shrink the wire bytes to them);
   3. runs the PR-2 grouped GEMMs over its LOCAL experts on the received
      rows (top_k=1 plan over local expert ids — padding rows hit expert 0
      with zero inputs and are never read back);
@@ -37,7 +36,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.kernels.moe import dispatch as dsp
@@ -109,7 +107,7 @@ def _ep_ffn_shard(xs, expert_idx, gates, w_gate, w_up, w_down, *,
     M = Tl * k
     # per-peer capacity: Tl*k is the droplessness bound (every local
     # assignment routed to one peer).  The all_to_all moves the full
-    # (ep, cap, d) layout on this JAX; the counts below are what a ragged
+    # (ep, cap, d) layout; the counts below are what a ragged
     # exchange would put on the wire.
     cap = M
     flat_e = expert_idx.reshape(M).astype(jnp.int32)
@@ -219,10 +217,10 @@ def ep_expert_ffn(x, expert_idx, gates, w_gate, w_up, w_down, mesh: Mesh, *,
                              block_m=block_m, impl=impl, tp=tp)
     tok = P(tok_axes)
     w_in, w_out = P(axis, None, tp), P(axis, tp, None)
-    smapped = shard_map(
+    smapped = jax.shard_map(
         body, mesh=mesh,
         in_specs=(tok, tok, tok, w_in, w_in, w_out),
-        out_specs=tok, check_rep=False)
+        out_specs=tok, check_vma=False)
     return _ep_apply(smapped, x, expert_idx, gates, w_gate, w_up, w_down)
 
 

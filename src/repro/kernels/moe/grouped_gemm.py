@@ -12,8 +12,8 @@ output block); at d_model <= 8k and block_m = 128 the (BM, K) + (K, BN)
 working set stays well inside VMEM.  Padding rows are zero and compute
 zeros — they are never read back by the combine scatter.
 
-``interpret=True`` (the default off-TPU) runs the same kernel under the
-Pallas interpreter, which is what CI's JAX_PLATFORMS=cpu leg exercises.
+``interpret`` is a required keyword: ``repro.kernels.moe.ops`` decides it
+from the backend (compiled on TPU, the Pallas interpreter in CPU tests).
 """
 from __future__ import annotations
 
@@ -42,7 +42,7 @@ def _kernel(e_ref, lhs_ref, rhs_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "interpret"))
 def grouped_matmul_pallas(lhs, rhs, tile_expert, *, block_m: int,
-                          block_n: int = 0, interpret: bool = True):
+                          block_n: int = 0, interpret: bool):
     """lhs: (m_pad, K), rhs: (E, K, N), tile_expert: (m_pad/block_m,) int32.
 
     Returns (m_pad, N) in lhs.dtype (f32 MXU accumulation).

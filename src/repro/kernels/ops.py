@@ -1,6 +1,9 @@
 """Jit'd public wrappers around the Pallas kernels: dtype/shape plumbing,
 head-dim padding to MXU-friendly multiples of 128, and interpret-mode
-selection (interpret=True everywhere except a real TPU backend).
+selection.  These selectors are the only place ``interpret`` is decided: the
+kernels run compiled on a TPU backend and under the Pallas interpreter
+everywhere else.  The raw ``pallas_call`` wrappers take it as a required
+keyword.
 """
 from __future__ import annotations
 
@@ -30,17 +33,14 @@ def _pad_last(x, mult: int):
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "window", "softcap",
-                                             "block_q", "block_k", "interpret"))
+                                             "block_q", "block_k"))
 def flash_attention(q, k, v, *, causal: bool = True,
                     window: Optional[int] = None,
                     softcap: Optional[float] = None,
-                    block_q: int = 128, block_k: int = 128,
-                    interpret: Optional[bool] = None):
+                    block_q: int = 128, block_k: int = 128):
     """q: (B,H,Sq,hd), k/v: (B,KV,Skv,hd).  Pads hd to a multiple of 128
     (zero-padding is exact: scores and outputs are unchanged; softmax scale
     keeps the original hd)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     hd = q.shape[-1]
     qp, _ = _pad_last(q, 128)
     kp, _ = _pad_last(k, 128)
@@ -48,7 +48,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
     out = _fa.flash_attention(qp, kp, vp, causal=causal, window=window,
                               softcap=softcap, scale=hd ** -0.5,
                               block_q=block_q, block_k=block_k,
-                              interpret=interpret)
+                              interpret=not _on_tpu())
     return out[..., :hd]
 
 
@@ -83,7 +83,17 @@ def _fat_fwd_lse(q, k, v, causal, window, softcap, block_q, block_k, impl):
     return out[..., :hd], lse
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _batch_mesh():
+    """The mesh a sharded train step runs under: the one entered with
+    ``jax.set_mesh``, else the expert-parallel mesh (``repro.core.settings``);
+    None on one device."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
+        from repro.core import settings
+        mesh = settings.EP_MESH
+    return mesh if mesh is not None and mesh.size > 1 else None
+
+
 def flash_attention_trainable(q, k, v, causal=True, window=None, softcap=None,
                               block_q=128, block_k=128, impl=None):
     """Differentiable flash attention: flash forward AND flash backward.
@@ -91,7 +101,29 @@ def flash_attention_trainable(q, k, v, causal=True, window=None, softcap=None,
     Residuals are (q, k, v, o, lse) — O(S) per head; the backward recomputes
     probability tiles from them (dq pass + dk/dv pass with in-kernel GQA
     reduction) instead of re-running a dense O(S^2) reference vjp.  ``impl``:
-    None (pallas on TPU, tiled jax elsewhere) | "pallas" | "jax"."""
+    None (pallas on TPU, tiled jax elsewhere) | "pallas" | "jax".
+
+    Under a multi-device mesh the Pallas kernels run per batch shard inside
+    a ``shard_map`` over the mesh's data axes: GSPMD cannot partition a
+    Mosaic kernel itself."""
+    args = (causal, window, softcap, block_q, block_k, impl)
+    mesh = _batch_mesh()
+    if mesh is None or _flash_impl(impl) == "jax":
+        return _flash_vjp(q, k, v, *args)
+    from jax.sharding import PartitionSpec as P
+    from repro.distributed.sharding import data_axes
+    axes = data_axes(mesh)
+    n = 1
+    for a in axes:
+        n *= mesh.shape[a]
+    spec = P(axes) if axes and q.shape[0] % n == 0 else P()
+    return jax.shard_map(lambda q_, k_, v_: _flash_vjp(q_, k_, v_, *args),
+                         mesh=mesh, in_specs=(spec,) * 3, out_specs=spec,
+                         check_vma=False)(q, k, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_vjp(q, k, v, causal, window, softcap, block_q, block_k, impl):
     out, _ = _fat_fwd_lse(q, k, v, causal, window, softcap,
                           block_q, block_k, impl)
     return out
@@ -126,33 +158,24 @@ def _fat_bwd(causal, window, softcap, block_q, block_k, impl, res, ct):
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-flash_attention_trainable.defvjp(_fat_fwd, _fat_bwd)
+_flash_vjp.defvjp(_fat_fwd, _fat_bwd)
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
-def rmsnorm(x, scale, eps: float = 1e-6, block_rows: int = 128,
-            interpret: Optional[bool] = None):
-    if interpret is None:
-        interpret = not _on_tpu()
+@functools.partial(jax.jit, static_argnames=("eps", "block_rows"))
+def rmsnorm(x, scale, eps: float = 1e-6, block_rows: int = 128):
     return _rn.rmsnorm(x, scale, eps=eps, block_rows=block_rows,
-                       interpret=interpret)
+                       interpret=not _on_tpu())
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def rwkv6_scan(r, k, v, w, u, *, chunk: int = 64,
-               interpret: Optional[bool] = None):
-    if interpret is None:
-        interpret = not _on_tpu()
-    return _rw.rwkv6_scan(r, k, v, w, u, chunk=chunk, interpret=interpret)
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def rwkv6_scan(r, k, v, w, u, *, chunk: int = 64):
+    return _rw.rwkv6_scan(r, k, v, w, u, chunk=chunk, interpret=not _on_tpu())
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
-def mamba_ssd(x, B_t, C_t, dt, log_a, *, chunk: int = 128,
-              interpret: Optional[bool] = None):
-    if interpret is None:
-        interpret = not _on_tpu()
+@functools.partial(jax.jit, static_argnames=("chunk",))
+def mamba_ssd(x, B_t, C_t, dt, log_a, *, chunk: int = 128):
     return _ms.mamba_ssd(x, B_t, C_t, dt, log_a, chunk=chunk,
-                         interpret=interpret)
+                         interpret=not _on_tpu())
 
 
 @jax.custom_vjp

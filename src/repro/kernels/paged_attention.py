@@ -19,8 +19,10 @@ convention as the grouped-MoE and flash kernels (DESIGN.md §7/§8):
   ``models.common._attend_cache`` bit-for-bit, which is what the engine's
   paged-vs-dense equivalence gate leans on.
 
-Interpret-mode Pallas (``interpret=True`` off-TPU) is for parity tests only,
-never the hot path.
+``paged_attention`` picks the implementation from the backend and, for the
+kernel, whether it runs compiled (TPU) or under the Pallas interpreter (CPU
+parity tests, never the hot path); ``paged_attention_pallas`` takes
+``interpret`` as a required keyword.
 
 Shapes (decode: one query position per slot):
 
@@ -112,7 +114,7 @@ def _paged_kernel(pt_ref, t_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
     q = q_ref[0].astype(jnp.float32)                       # (H, hd)
     k = k_ref[0].astype(jnp.float32)                       # (page, KV, hd)
     v = v_ref[0].astype(jnp.float32)
-    pos = pos_ref[...]                                     # (1, page)
+    pos = pos_ref[0]                                       # (1, page)
     H, hd = q.shape
     KV = k.shape[1]
     G = H // KV
@@ -158,13 +160,11 @@ def _paged_kernel(pt_ref, t_ref, q_ref, k_ref, v_ref, pos_ref, o_ref,
 def paged_attention_pallas(q, k_pool, v_pool, pos_pool, page_table, t, *,
                            kv_len: int, window: Optional[int] = None,
                            softcap: Optional[float] = None,
-                           interpret: Optional[bool] = None):
+                           interpret: bool):
     """Pallas paged decode attention.  Grid (B, n_pages); the page table is a
     scalar-prefetch operand so each step's K/V/pos blocks are fetched from
     the physical page ``page_table[b, j]`` (clipped for unmapped entries,
     which the in-kernel validity mask then zeroes out)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     B, H, hd = q.shape
     P, page, KV, _ = k_pool.shape
     n_pages = page_table.shape[1]
@@ -180,9 +180,9 @@ def paged_attention_pallas(q, k_pool, v_pool, pos_pool, page_table, t, *,
             pl.BlockSpec((1, page, KV, hd),
                          lambda b, j, pt, tt:
                          (jnp.clip(pt[b, j], 0, P - 1), 0, 0, 0)),
-            pl.BlockSpec((1, page),
+            pl.BlockSpec((1, 1, page),
                          lambda b, j, pt, tt:
-                         (jnp.clip(pt[b, j], 0, P - 1), 0)),
+                         (jnp.clip(pt[b, j], 0, P - 1), 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, H, hd), lambda b, j, pt, tt: (b, 0, 0)),
         scratch_shapes=[
@@ -199,7 +199,7 @@ def paged_attention_pallas(q, k_pool, v_pool, pos_pool, page_table, t, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
         interpret=interpret,
-    )(page_table, t, q, k_pool, v_pool, pos_pool)
+    )(page_table, t, q, k_pool, v_pool, pos_pool.reshape(P, 1, page))
     return out.reshape(B, H * hd)
 
 
@@ -217,8 +217,7 @@ def _impl(impl: Optional[str]) -> str:
 
 def paged_attention(q, k_pool, v_pool, pos_pool, page_table, t, *,
                     kv_len: int, window=None, softcap=None,
-                    impl: Optional[str] = None,
-                    interpret: Optional[bool] = None):
+                    impl: Optional[str] = None):
     """Paged decode attention; returns (B, H*hd).
 
     ``impl``: None (pallas on TPU, gather-jax elsewhere) | "pallas" | "jax".
@@ -230,4 +229,4 @@ def paged_attention(q, k_pool, v_pool, pos_pool, page_table, t, *,
                                    softcap=softcap)
     return paged_attention_pallas(q, k_pool, v_pool, pos_pool, page_table,
                                   t, kv_len=kv_len, window=window,
-                                  softcap=softcap, interpret=interpret)
+                                  softcap=softcap, interpret=not _on_tpu())

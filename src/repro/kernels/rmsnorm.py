@@ -19,8 +19,8 @@ def _kernel(x_ref, s_ref, o_ref, *, eps: float):
                   * (1.0 + s_ref[...].astype(jnp.float32))).astype(o_ref.dtype)
 
 
-def rmsnorm(x, scale, eps: float = 1e-6, block_rows: int = 128,
-            interpret: bool = True):
+def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 128,
+            interpret: bool):
     """x: (..., d); scale: (d,)."""
     orig_shape = x.shape
     d = orig_shape[-1]

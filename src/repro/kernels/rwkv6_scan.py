@@ -63,7 +63,7 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, o_ref, s_scr, *, T: int, C: int)
                   jax.lax.dot(kd.T, v, preferred_element_type=jnp.float32))
 
 
-def rwkv6_scan(r, k, v, w, u, *, chunk: int = 64, interpret: bool = True):
+def rwkv6_scan(r, k, v, w, u, *, chunk: int = 64, interpret: bool):
     """r,k,v: (B,H,S,C); w: decay in (0,1) (B,H,S,C); u: (H,C).
     Returns out (B,H,S,C).  S must be a multiple of ``chunk``."""
     B, H, S, C = r.shape

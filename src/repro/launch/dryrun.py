@@ -105,7 +105,7 @@ def lower_cell(arch: str, shape_name: str, mesh, *, compile_: bool = True,
               "kind": shape.kind}
 
     t0 = time.time()
-    with shd.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         if shape.kind == "train":
             opt = AdamW(lr=1e-5)
             aopt = jax.eval_shape(opt.init, aparams)
@@ -117,8 +117,8 @@ def lower_cell(arch: str, shape_name: str, mesh, *, compile_: bool = True,
             step = make_train_step(model, opt, n_micro=nm)
             jitted = jax.jit(
                 step,
-                in_shardings=shd.jit_shardings((pspecs, opt_pspecs, bspecs), mesh),
-                out_shardings=shd.jit_shardings((pspecs, opt_pspecs, None), mesh),
+                in_shardings=(pspecs, opt_pspecs, bspecs),
+                out_shardings=(pspecs, opt_pspecs, None),
                 donate_argnums=(0, 1))
             lowered = jitted.lower(aparams, aopt, batch)
         else:
@@ -150,8 +150,8 @@ def lower_cell(arch: str, shape_name: str, mesh, *, compile_: bool = True,
 
             jitted = jax.jit(
                 serve_step,
-                in_shardings=shd.jit_shardings((pspecs, cspecs, tspec), mesh),
-                out_shardings=shd.jit_shardings((None, cspecs), mesh),
+                in_shardings=(pspecs, cspecs, tspec),
+                out_shardings=(None, cspecs),
                 donate_argnums=(1,))
             lowered = jitted.lower(aparams, acache, tok)
 

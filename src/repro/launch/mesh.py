@@ -9,10 +9,24 @@ carved out of the data dimension: the same devices that were pure data
 replicas additionally own a slice of the expert axis, and the MoE layer's
 shard_map all-to-all runs over that axis while FSDP/batch sharding keeps
 using the remaining "data"/"pod" axes.
+
+Every axis is ``AxisType.Auto``: the sharding rules place parameters and
+batches with PartitionSpecs and let GSPMD propagate the rest, which is what
+``jax.make_mesh``'s default of ``Explicit`` axes would refuse.
 """
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
+from jax.sharding import AxisType
+
+
+def make_mesh(shape: Sequence[int], axes: Sequence[str], devices=None):
+    """``jax.make_mesh`` with every axis ``Auto`` (see module docstring)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False, expert: int = 1):
@@ -26,10 +40,10 @@ def make_production_mesh(*, multi_pod: bool = False, expert: int = 1):
         axes = ("data", "expert", "model")
         if multi_pod:
             shape, axes = (2,) + shape, ("pod",) + axes
-        return jax.make_mesh(shape, axes)
+        return make_mesh(shape, axes)
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_debug_mesh(data: int = 1, model: int = 1, expert: int = 0):
@@ -37,6 +51,5 @@ def make_debug_mesh(data: int = 1, model: int = 1, expert: int = 0):
     appends an "expert" axis of that size (an explicit size-1 axis is valid:
     the EP dispatch path runs unchanged with a single expert shard)."""
     if expert > 0:
-        return jax.make_mesh((data, expert, model),
-                             ("data", "expert", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((data, expert, model), ("data", "expert", "model"))
+    return make_mesh((data, model), ("data", "model"))

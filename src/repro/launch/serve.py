@@ -12,13 +12,70 @@ sampling, EOS/length termination on device) and reports throughput.
 ``--paged`` serves from the block-paged KV pool with radix prefix sharing
 (DESIGN.md §15); ``--shared-prefix N`` gives every request the same
 N-token system prefix so the prefix cache has something to hit.  Reduced
-(smoke-scale) configs are the default on this CPU container;
-``--no-reduced`` serves the full config (real accelerator only).
+(smoke-scale) configs are the default, for CPU runs; ``--no-reduced`` serves
+the full config (TPU).
 """
 from __future__ import annotations
 
 import argparse
 import time
+
+#: smallest token id the synthetic prompts use (ids below are special)
+LO_TOKEN = 4
+
+
+def serve_requests(eng, prompts, *, gen: int, vocab_size: int,
+                   temperature: float = 0.0, top_k: int = 0,
+                   top_p: float = 1.0, warmup: bool = True):
+    """Serve ``prompts`` (one request each, ``gen`` new tokens, no EOS).
+
+    The warmup pass MIRRORS the workload — same prompt lengths, same
+    shared-prefix structure, shifted token values — so admission touches
+    every prefill bucket the real run will use, including the
+    radix-shortened SUFFIX buckets in paged mode.  Then the compiled-signature
+    set is frozen: any further compile is a post-warmup recompile.
+
+    Returns (done requests by uid, wall seconds of the measured run,
+    post-warmup recompiles)."""
+    import numpy as np
+    from repro.serving.engine import Request
+
+    if warmup:
+        span = max(vocab_size - LO_TOKEN, 1)
+        for i, p in enumerate(prompts):
+            wp = (LO_TOKEN + (p - LO_TOKEN + 1) % span).astype(np.int32)
+            eng.submit(Request(uid=1_000_000 + i, prompt=wp,
+                               max_new_tokens=2, eos_id=-1,
+                               temperature=temperature, seed=i))
+        eng.run()
+        eng.done.clear()
+    warm = eng.mark_warm()
+
+    for uid, prompt in enumerate(prompts):
+        eng.submit(Request(uid=uid, prompt=prompt, max_new_tokens=gen,
+                           eos_id=-1, temperature=temperature,
+                           top_k=top_k, top_p=top_p, seed=uid))
+    t0 = time.perf_counter()
+    done = eng.run()
+    dt = time.perf_counter() - t0
+    now = eng.jit_cache_sizes()
+    recompiles = sum(max(now[k] - warm[k], 0) for k in now)
+    return done, dt, recompiles
+
+
+def make_prompts(rng, n: int, *, lo_len: int, hi_len: int,
+                 shared_prefix: int, vocab_size: int):
+    """``n`` random prompts of ``lo_len..hi_len`` tokens, each behind the
+    same ``shared_prefix``-token system prefix (none when 0)."""
+    import numpy as np
+    sys_prefix = rng.integers(LO_TOKEN, vocab_size,
+                              size=shared_prefix).astype(np.int32)
+    prompts = []
+    for _ in range(n):
+        plen = int(rng.integers(lo_len, max(lo_len + 1, hi_len + 1)))
+        tail = rng.integers(LO_TOKEN, vocab_size, size=plen).astype(np.int32)
+        prompts.append(np.concatenate([sys_prefix, tail]))
+    return prompts
 
 
 def main():
@@ -73,9 +130,11 @@ def main():
     import jax
     import numpy as np
     from repro.configs.base import get_config
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.models.model import Model
-    from repro.serving.engine import Request, ServingEngine
+    from repro.serving.engine import ServingEngine
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
@@ -101,42 +160,14 @@ def main():
                         kv_budget_gb=args.kv_budget_gb,
                         prefix_cache=args.prefix_cache)
 
-    rng = np.random.default_rng(0)
-    lo = 4
-    sys_prefix = rng.integers(lo, cfg.vocab_size,
-                              size=args.shared_prefix).astype(np.int32)
-    prompts = []
-    for uid in range(args.requests):
-        plen = int(rng.integers(4, max(5, args.prompt_len + 1)))
-        tail = rng.integers(lo, cfg.vocab_size, size=plen).astype(np.int32)
-        prompts.append(np.concatenate([sys_prefix, tail])
-                       if args.shared_prefix else tail)
-
-    if not args.no_warmup:
-        # warmup MIRRORS the workload — same prompt lengths, same
-        # shared-prefix structure, shifted token values — so admission
-        # touches every prefill bucket the real run will use, including the
-        # radix-shortened SUFFIX buckets in paged mode.  Then freeze the
-        # compiled-signature set: any further compile is flagged by the
-        # recompile watchdog (serve.recompiles_post_warmup must stay 0).
-        span = max(cfg.vocab_size - lo, 1)
-        for i, p in enumerate(prompts):
-            wp = (lo + (p - lo + 1) % span).astype(np.int32)
-            eng.submit(Request(uid=1_000_000 + i, prompt=wp,
-                               max_new_tokens=2, eos_id=-1,
-                               temperature=args.temperature, seed=i))
-        eng.run()
-        eng.done.clear()
-    eng.mark_warm()
-
-    for uid, prompt in enumerate(prompts):
-        eng.submit(Request(uid=uid, prompt=prompt, max_new_tokens=args.gen,
-                           eos_id=-1, temperature=args.temperature,
-                           top_k=args.top_k, top_p=args.top_p, seed=uid))
-
-    t0 = time.perf_counter()
-    done = eng.run()
-    dt = time.perf_counter() - t0
+    prompts = make_prompts(np.random.default_rng(0), args.requests,
+                           lo_len=4, hi_len=args.prompt_len,
+                           shared_prefix=args.shared_prefix,
+                           vocab_size=cfg.vocab_size)
+    done, dt, recompiles = serve_requests(
+        eng, prompts, gen=args.gen, vocab_size=cfg.vocab_size,
+        temperature=args.temperature, top_k=args.top_k, top_p=args.top_p,
+        warmup=not args.no_warmup)
     n_tok = sum(len(r.generated) for r in done.values())
     print(f"[serve] {args.arch}: {len(done)} requests, {n_tok} tokens, "
           f"{n_tok / dt:.1f} tok/s (slots={args.slots}, "
@@ -144,8 +175,7 @@ def main():
           f"temperature={args.temperature}, top_k={args.top_k}, "
           f"top_p={args.top_p})")
     print(f"[serve] jit cache: {eng.jit_cache_sizes()} "
-          f"(post-warmup recompiles: "
-          f"{tel.counter('serve.recompiles_post_warmup').value if tel.enabled else 'n/a'})")
+          f"(post-warmup recompiles: {recompiles})")
     if args.paged:
         hits = (tel.counter("serve.prefix_hits").value
                 if tel.enabled else "n/a")

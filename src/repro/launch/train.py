@@ -2,13 +2,11 @@
 
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-moe-a2.7b \
         [--reduced] [--steps 100] [--stage1 20] [--optimizer adamw|lomo|galore] \
-        [--mesh debug|pod|multipod] [--compress]
+        [--ckpt-dir DIR] [--compress]
 
-On this CPU container use --reduced (smoke-scale).  On a real cluster the
-same entrypoint runs the full config under the production mesh: parameters,
-gradients and optimizer state shard per repro.distributed.sharding (ZeRO-3 +
-TP + EP), the data pipeline shards by process, checkpoints are atomic and
-resumable (see repro.train.driver).
+On CPU (JAX_PLATFORMS=cpu) use --reduced (smoke-scale); a TPU runs the
+published widths.  Checkpoints are written, and resumed from, only when
+--ckpt-dir is given (atomic and resumable, see repro.train.driver).
 """
 from __future__ import annotations
 
@@ -28,7 +26,9 @@ def main():
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--n-micro", type=int, default=1)
     ap.add_argument("--log-every", type=int, default=10)
-    ap.add_argument("--ckpt-dir", default="/tmp/repro_train")
+    ap.add_argument("--ckpt-dir", default=None,
+                    help="checkpoint every steps/5 into (and resume from) "
+                         "DIR; default: no checkpoints")
     ap.add_argument("--compress", action="store_true",
                     help="int8 gradient compression before reduction")
     ap.add_argument("--fused-optimizer", action="store_true",
@@ -50,9 +50,9 @@ def main():
     ap.add_argument("--ep", type=int, default=0,
                     help="expert-parallel degree (kernels/moe/ep): shards "
                          "experts+tokens over a dedicated 'expert' mesh "
-                         "axis with an all-to-all dispatch; on CPU, fake "
-                         "host devices are forced so --reduced smoke runs "
-                         "exercise the real multi-device path")
+                         "axis with an all-to-all dispatch; the CPU backend "
+                         "is given that many host devices so --reduced "
+                         "smoke runs exercise the real multi-device path")
     ap.add_argument("--layer-groups", type=int, default=0,
                     help="lean parameterization (DESIGN.md §14): share each "
                          "main-stack layer's matrices across N layer groups "
@@ -84,14 +84,13 @@ def main():
                          "summarize PATH`)")
     args = ap.parse_args()
 
-    if args.ep > 1:
-        # must happen before the jax import: smoke runs on this CPU-only
-        # container need enough (fake) devices to carry the expert axis
-        import os
-        os.environ.setdefault(
-            "XLA_FLAGS", f"--xla_force_host_platform_device_count={args.ep}")
-
     import jax
+    if args.ep > 1:
+        # sizes the CPU backend only (before it initialises); a TPU host
+        # keeps its own devices
+        jax.config.update("jax_num_cpu_devices", args.ep)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from repro.configs.base import get_config
     from repro.data.pipeline import DataConfig
     from repro.models.model import Model
@@ -136,7 +135,8 @@ def main():
                     num_hosts=jax.process_count(),
                     host_id=jax.process_index())
     rc = RunConfig(total_steps=args.steps, stage1_steps=args.stage1,
-                   ckpt_every=max(args.steps // 5, 1), ckpt_dir=args.ckpt_dir,
+                   ckpt_every=max(args.steps // 5, 1),
+                   ckpt_dir=args.ckpt_dir,
                    log_every=args.log_every, n_micro=args.n_micro,
                    audit_every=args.audit_every,
                    fused_optimizer=args.fused_optimizer)

@@ -482,24 +482,35 @@ def train_step_flops(model, batch: int, seq: int, save_memory=True) -> float:
     return mult * fwd
 
 
-#: nominal peak FLOP/s per device platform for the MFU denominator (TPU v5e
-#: bf16 MXU; A100-class bf16; a token CPU figure so reduced smoke runs emit
-#: a finite, obviously-not-hardware-bound gauge).  Override with the
-#: REPRO_PEAK_FLOPS env var on other hardware.
-PEAK_FLOPS_BY_PLATFORM = {"tpu": 197e12, "gpu": 312e12, "cpu": 1e11}
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip peaks: the MFU and roofline denominators."""
+    bf16_flops_per_s: float
+    hbm_bytes_per_s: float
+    source: str
 
 
-def peak_flops() -> float:
-    import os
-    env = os.environ.get("REPRO_PEAK_FLOPS")
-    if env:
-        return float(env)
+#: peaks keyed by ``jax.Device.device_kind``.  A device that is not here has
+#: no peak: ``device_peaks`` raises instead of guessing one.
+DEVICE_PEAKS = {
+    "TPU v5 lite": DevicePeaks(
+        bf16_flops_per_s=197e12, hbm_bytes_per_s=819e9,
+        source="Google Cloud documentation, 'TPU v5e'"),
+}
+
+
+def device_peaks(device=None) -> DevicePeaks:
+    """Peaks of ``device`` (default: device 0).  CPU runs report counts
+    only, so a CPU device — like any kind missing from ``DEVICE_PEAKS`` —
+    is an error."""
+    device = device if device is not None else jax.devices()[0]
     try:
-        platform = jax.devices()[0].platform
-    except Exception:  # noqa: BLE001
-        platform = "cpu"
-    return PEAK_FLOPS_BY_PLATFORM.get(platform,
-                                      PEAK_FLOPS_BY_PLATFORM["cpu"])
+        return DEVICE_PEAKS[device.device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device kind {device.device_kind!r} "
+            f"(platform {device.platform}); known: "
+            f"{sorted(DEVICE_PEAKS)}") from None
 
 
 def device_memory_stats() -> Optional[dict]:

@@ -3,10 +3,12 @@ SwiGLU MLP.  All pure functions over explicit param dicts built from ParamSpec.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models.spec import ParamSpec
@@ -20,6 +22,48 @@ def rms_norm(x, scale, eps: float = 1e-6):
     var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
     out = x * jax.lax.rsqrt(var + eps) * (1.0 + scale.astype(jnp.float32))
     return out.astype(dtype)
+
+
+def embed_lookup(table, tokens):
+    """Rows of ``table`` at ``tokens``; the backward sums each row in f32.
+
+    A frequent token's gradient row sums thousands of contributions, and a
+    bf16 scatter-add drops most of them (4096 equal contributions sum to a
+    tenth of their total; its order, e.g. one device vs FSDP shards, moves a
+    row by tens of percent).  The backward sorts the contributions by token,
+    sums each token's run in f32 (a (tokens, d) buffer), and writes every
+    row once, so no (vocab, d) f32 buffer is needed."""
+    return _take_rows(table, tokens, table.shape[0])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _take_rows(table, tokens, n_rows):
+    return jnp.take(table, tokens, axis=0)
+
+
+def _take_rows_fwd(table, tokens, n_rows):
+    return jnp.take(table, tokens, axis=0), tokens
+
+
+def _take_rows_bwd(n_rows, tokens, ct):
+    flat = tokens.reshape(-1)
+    n = flat.shape[0]
+    order = jnp.argsort(flat)
+    rows = flat[order]
+    # one segment per distinct token, in sorted order
+    seg = jnp.cumsum(jnp.concatenate(
+        [jnp.zeros((1,), jnp.int32), (rows[1:] != rows[:-1]).astype(jnp.int32)]))
+    sums = jax.ops.segment_sum(
+        ct.reshape(n, -1)[order].astype(jnp.float32), seg, num_segments=n,
+        indices_are_sorted=True)
+    # unused segments add exact zeros to row 0
+    seg_row = jnp.zeros((n,), flat.dtype).at[seg].set(rows)
+    g = jnp.zeros((n_rows, ct.shape[-1]), ct.dtype).at[seg_row].add(
+        sums.astype(ct.dtype))
+    return g, np.zeros(tokens.shape, jax.dtypes.float0)
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
 def norm_spec(dim: int) -> ParamSpec:

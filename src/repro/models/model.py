@@ -35,8 +35,8 @@ from repro.core.reversible import (chain, coupling, grouped_mixed_policy_stack,
 from repro.models import common, moe as moe_lib, spec, ssm as ssm_lib
 from repro.models.common import (attention, attention_decode, attn_specs,
                                  cross_attention_decode, cross_kv,
-                                 init_kv_cache, lm_head_logits, mlp, mlp_specs,
-                                 norm_spec, rms_norm, softcap)
+                                 embed_lookup, init_kv_cache, lm_head_logits,
+                                 mlp, mlp_specs, norm_spec, rms_norm, softcap)
 from repro.models.spec import ParamSpec
 
 BIG_WINDOW = 1 << 30
@@ -773,7 +773,7 @@ class Model:
         cfg = self.cfg
         assert cfg.reversible, "layer audit requires cfg.reversible=True"
         B, S = tokens.shape
-        h = jnp.take(params["embed"], tokens, axis=0)
+        h = embed_lookup(params["embed"], tokens)
         h = self._constrain(h)
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None],
                                      (B, S))
@@ -806,7 +806,7 @@ class Model:
         """
         cfg = self.cfg
         B, S = tokens.shape
-        h = jnp.take(params["embed"], tokens, axis=0)
+        h = embed_lookup(params["embed"], tokens)
         h = self._constrain(h)
         positions = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
         ctx = {"positions": positions}

@@ -175,14 +175,17 @@ class ServingEngine:
         self.queue: deque = deque()
         self.done: Dict[int, Request] = {}
 
-        def _decode_hidden(cache_slot, tok):
+        # the jitted entry points take ``params`` as an argument: closed
+        # over, the weights would be baked into each program as constants
+        # (GBs of host memory per compile at published widths)
+        def _decode_hidden(params, cache_slot, tok):
             return model.decode_step_hidden(params, cache_slot, tok)
 
-        def _prefill_hidden(cache_slot, tok, n):
+        def _prefill_hidden(params, cache_slot, tok, n):
             return model.decode_step_hidden(params, cache_slot, tok,
                                             seq_len=n)
 
-        def _first_token(h, lengths, seeds, temps, top_ks, top_ps):
+        def _first_token(params, h, lengths, seeds, temps, top_ks, top_ps):
             """Sample token 0 for every slot from the last real prefill
             position (shared by the dense and paged admission paths)."""
             idx = jnp.clip(lengths - 1, 0, h.shape[2] - 1)
@@ -194,11 +197,12 @@ class ServingEngine:
             return jax.vmap(sampling.sample_token)(
                 logits.astype(jnp.float32), keys0, temps, top_ks, top_ps)
 
-        def _steps(cache, st):
+        def _steps(params, cache, st):
             def one(carry, _):
                 cache, st = carry
                 tok_in = st["last_tok"].reshape(slots, 1, 1)
-                h, cache = jax.vmap(_decode_hidden)(cache, tok_in)
+                h, cache = jax.vmap(_decode_hidden, in_axes=(None, 0, 0))(
+                    params, cache, tok_in)
                 logits = model.lm_logits(params, h[:, 0, -1])   # (slots, V)
                 tok = sampling.sample(logits, st)
                 return (cache, sampling.advance(st, tok)), None
@@ -206,13 +210,15 @@ class ServingEngine:
                                           length=self.drain_every)
             return cache, st
 
-        def _prefill_admit(cache, fresh, st, tokens, lengths, admit,
+        def _prefill_admit(params, cache, fresh, st, tokens, lengths, admit,
                            seeds, temps, top_ks, top_ps, eos_ids, max_news):
             """Batched bucketed prefill + admission splice, one compile per
             bucket length.  tokens: (slots, 1, Lb) right-padded; only rows
             selected by ``admit`` are spliced in."""
-            h, pre = jax.vmap(_prefill_hidden)(fresh, tokens, lengths)
-            tok0 = _first_token(h, lengths, seeds, temps, top_ks, top_ps)
+            h, pre = jax.vmap(_prefill_hidden, in_axes=(None, 0, 0, 0))(
+                params, fresh, tokens, lengths)
+            tok0 = _first_token(params, h, lengths, seeds, temps, top_ks,
+                                top_ps)
 
             def splice(path, eng, new):
                 m = admit.reshape((slots,) + (1,) * (eng.ndim - 1))
@@ -235,7 +241,7 @@ class ServingEngine:
 
         # ------------------------------------------------ paged jitted fns
 
-        def _steps_paged(pool, pt, tvec, st):
+        def _steps_paged(params, pool, pt, tvec, st):
             def one(carry, _):
                 pool, tvec, st = carry
                 tok_in = st["last_tok"].reshape(slots, 1)
@@ -249,7 +255,7 @@ class ServingEngine:
                                                length=self.drain_every)
             return pool, tvec, st
 
-        def _prefill_admit_paged(pool, pt, tvec, fresh, st, tokens,
+        def _prefill_admit_paged(params, pool, pt, tvec, fresh, st, tokens,
                                  suffix_lens, plens, m_vec, admit, seeds,
                                  temps, top_ks, top_ps, eos_ids, max_news):
             """Paged admission, one compile per SUFFIX bucket: gather the
@@ -278,8 +284,10 @@ class ServingEngine:
                                        jidx[None, None, :], fkv["pos"])
                 seeded[name] = {"kv": out}
 
-            h, pre = jax.vmap(_prefill_hidden)(seeded, tokens, suffix_lens)
-            tok0 = _first_token(h, suffix_lens, seeds, temps, top_ks, top_ps)
+            h, pre = jax.vmap(_prefill_hidden, in_axes=(None, 0, 0, 0))(
+                params, seeded, tokens, suffix_lens)
+            tok0 = _first_token(params, h, suffix_lens, seeds, temps, top_ks,
+                                top_ps)
 
             pageof = jnp.clip(jidx // pg, 0, maxp - 1)
             phys = pt[:, pageof]                                # (slots, C)
@@ -436,7 +444,8 @@ class ServingEngine:
             with self.tel.span("serve.prefill_admit", bucket=int(lb),
                                n=len(batch)):
                 self.cache, self.sstate = self._admit_fn(
-                    self.cache, self._fresh, self.sstate, jnp.asarray(tokens),
+                    self.params, self.cache, self._fresh, self.sstate,
+                    jnp.asarray(tokens),
                     jnp.asarray(lengths), jnp.asarray(admit),
                     jnp.asarray(seeds), jnp.asarray(temps),
                     jnp.asarray(top_ks), jnp.asarray(top_ps),
@@ -544,7 +553,7 @@ class ServingEngine:
             with self.tel.span("serve.prefill_admit", bucket=int(lb),
                                n=len(placed)):
                 self.pool, self._tvec, self.sstate = self._admit_fn(
-                    self.pool, self._pt, self._tvec, self._fresh,
+                    self.params, self.pool, self._pt, self._tvec, self._fresh,
                     self.sstate, jnp.asarray(tokens),
                     jnp.asarray(suffix_lens), jnp.asarray(plens),
                     jnp.asarray(m_vec), jnp.asarray(admit),
@@ -641,9 +650,11 @@ class ServingEngine:
         with self.tel.span("serve.decode_window", steps=self.drain_every):
             if self.paged:
                 self.pool, self._tvec, self.sstate = self._step_fn(
-                    self.pool, self._pt, self._tvec, self.sstate)
+                    self.params, self.pool, self._pt, self._tvec,
+                    self.sstate)
             else:
-                self.cache, self.sstate = self._step_fn(self.cache,
+                self.cache, self.sstate = self._step_fn(self.params,
+                                                        self.cache,
                                                         self.sstate)
         self._drain()
         self._recompile_wd.check()

@@ -30,7 +30,7 @@ class RunConfig:
     total_steps: int = 100
     stage1_steps: int = 20          # adapter warm-up (paper §3.3)
     ckpt_every: int = 20
-    ckpt_dir: str = "/tmp/repro_ckpt"
+    ckpt_dir: Optional[str] = None  # None: no checkpoints, no resume
     log_every: int = 10
     n_micro: int = 1
     audit_every: int = 0            # reversible audit cadence (0 = off, §12)
@@ -61,17 +61,13 @@ def _predicted_peak_bytes(model, optimizer, batch: int, seq: int,
 
 
 def _make_auditor(model, tel, save_memory):
-    """Build the layer auditor lazily at the first audit window.  Guarded:
-    any construction failure (non-reversible config, estimator gaps...)
-    disables audit mode instead of taking the run down."""
-    try:
-        from repro.obs.audit import LayerAuditor, policies_for
-        policies = policies_for(model, save_memory)
-        if policies is None:
-            return None
-        return LayerAuditor(model, tel, policies)
-    except Exception:  # noqa: BLE001
+    """Build the layer auditor lazily at the first audit window (None when
+    the config has no reversible stack to audit)."""
+    from repro.obs.audit import LayerAuditor, policies_for
+    policies = policies_for(model, save_memory)
+    if policies is None:
         return None
+    return LayerAuditor(model, tel, policies)
 
 
 def train(model, optimizer, data_cfg: DataConfig, run: RunConfig,
@@ -117,7 +113,7 @@ def train(model, optimizer, data_cfg: DataConfig, run: RunConfig,
     layouts = {s.name: s.layout.describe()
                for s in model.stacks if s.layout is not None} or None
 
-    latest = ckpt.latest_step(run.ckpt_dir)
+    latest = ckpt.latest_step(run.ckpt_dir) if run.ckpt_dir else None
     if latest is not None:
         t_rs = time.perf_counter()
         (params, opt_state), start_step = ckpt.restore(
@@ -144,13 +140,13 @@ def train(model, optimizer, data_cfg: DataConfig, run: RunConfig,
     flops_per_step = peak = None
     memw = None
     if tel.enabled:
-        try:
+        if jax.devices()[0].platform != "cpu":
+            # MFU is a device metric: a CPU run reports counts only, and an
+            # accelerator without a published peak is an error, not a guess
             from repro.memory import estimator as est
             flops_per_step = est.train_step_flops(
                 model, data_cfg.global_batch, data_cfg.seq_len, save_memory)
-            peak = est.peak_flops()
-        except Exception:  # noqa: BLE001
-            pass
+            peak = est.device_peaks().bf16_flops_per_s
         memw = obs.MemoryWatchdog(tel, _predicted_peak_bytes(
             model, optimizer, micro_b, data_cfg.seq_len, save_memory,
             fused=run.fused_optimizer))
@@ -219,17 +215,14 @@ def train(model, optimizer, data_cfg: DataConfig, run: RunConfig,
                 # warm/check bracket the audit call alone: stage 2's later
                 # first compile must not read as an audit-induced recompile
                 audit_watch.mark_warm()
-                try:
-                    ab = {k: v[:micro_b] for k, v in batch.items()}
-                    with tel.span("audit", observe=False):
-                        auditor.run(params, ab, step + 1)
-                except Exception:  # noqa: BLE001 — diagnostics never fatal
-                    audit_on = False
+                ab = {k: v[:micro_b] for k, v in batch.items()}
+                with tel.span("audit", observe=False):
+                    auditor.run(params, ab, step + 1)
                 audit_watch.check()
         if (step + 1) % run.log_every == 0:
             emit_window(step)
             window_s, window_steps = 0.0, 0
-        if (step + 1) % run.ckpt_every == 0:
+        if run.ckpt_dir and (step + 1) % run.ckpt_every == 0:
             t_sv = time.perf_counter()
             ckpt.save(run.ckpt_dir, step + 1, (params, opt_state),
                       extra_meta={"layouts": layouts})

@@ -313,3 +313,25 @@ def test_train_step_accepts_plan(setup):
         model, opt, save_memory=["offload", "reversible", "remat", "store"]))
     p2, st2, metrics = step(params, opt.init(params), batch)
     assert bool(jnp.isfinite(metrics["loss"]))
+
+
+class _Device:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+@pytest.mark.parametrize("platform,kind", [("tpu", "TPU v5 lite"),
+                                           ("cpu", "cpu"),
+                                           ("tpu", "TPU v4")])
+def test_device_peaks_keyed_by_kind(platform, kind):
+    """Peaks come from the published table by device kind; a CPU or an
+    accelerator missing from it is an error, never a guessed figure."""
+    dev = _Device(platform, kind)
+    if kind in est_mod.DEVICE_PEAKS:
+        peaks = est_mod.device_peaks(dev)
+        assert peaks.bf16_flops_per_s == 197e12
+        assert peaks.hbm_bytes_per_s == 819e9
+        assert "TPU v5e" in peaks.source
+    else:
+        with pytest.raises(ValueError, match="no published peaks"):
+            est_mod.device_peaks(dev)

@@ -165,3 +165,21 @@ def test_prefill_longer_than_rolling_buffer():
     inc = jnp.stack(outs, axis=1)
     np.testing.assert_allclose(np.asarray(full), np.asarray(inc),
                                rtol=2e-3, atol=2e-3)
+
+
+def test_embed_lookup_accumulates_gradient_rows_in_f32():
+    """A token seen 4096 times: its bf16 gradient row is the f32 sum of all
+    contributions, rounded once (a bf16 running sum stalls far below)."""
+    from repro.models.common import embed_lookup
+    table = jnp.zeros((8, 4), jnp.bfloat16)
+    tokens = jnp.zeros((2, 2048), jnp.int32).at[:, :3].set(5)
+    ct = jnp.full((2, 2048, 4), 0.01, jnp.bfloat16)
+    out, vjp = jax.vjp(lambda t: embed_lookup(t, tokens), table)
+    (g,) = vjp(ct)
+    assert out.shape == (2, 2048, 4) and g.dtype == jnp.bfloat16
+    exact = float(jnp.asarray(0.01, jnp.bfloat16).astype(jnp.float32))
+    np.testing.assert_allclose(np.asarray(g[0], np.float32),
+                               exact * (4096 - 6), rtol=4e-3)
+    np.testing.assert_allclose(np.asarray(g[5], np.float32), exact * 6,
+                               rtol=4e-3)
+    assert not np.asarray(g[1:5], np.float32).any()

@@ -17,7 +17,7 @@ from repro.configs.base import ARCHS, get_config
 from repro.core import settings
 from repro.core.reversible import make_coupled
 from repro.distributed import sharding as shd
-from repro.launch.mesh import make_debug_mesh
+from repro.launch.mesh import make_debug_mesh, make_mesh
 from repro.models import moe as moe_lib
 from repro.models.model import Model
 from repro.models.spec import initialize
@@ -53,7 +53,7 @@ def test_param_pspecs_place_on_real_mesh(shape, axes):
     """Every arch's param specs must be *placeable* on a real mesh: each
     NamedSharding shard_shape call validates divisibility against actual
     devices, not the _FakeMesh arithmetic of tests/test_sharding.py."""
-    mesh = jax.make_mesh(shape, axes)
+    mesh = make_mesh(shape, axes)
     for arch in ARCHS:
         model = Model(get_config(arch))
         aparams = model.abstract_params()
@@ -71,7 +71,7 @@ def test_jit_loss_sharded_2x4():
     jitted reversible loss + grad on a real 2x4 mesh — the
     reversible-recompute-under-sharding interaction that used to ship
     untested (conftest pinned everything to one device)."""
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_debug_mesh(data=2, model=4)
     cfg = get_config("qwen2-moe-a2.7b", reduced=True).replace(
         num_layers=2, moe_backend="grouped")
     model = Model(cfg)
@@ -84,7 +84,7 @@ def test_jit_loss_sharded_2x4():
     bspec = shd.batch_pspec(mesh, 4, 2)
     assert bspec == P("data", None)
     batch = jax.device_put(batch, NamedSharding(mesh, bspec))
-    with shd.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         loss, grads = jax.jit(jax.value_and_grad(
             lambda p, b: model.loss(p, b)))(params, batch)
     assert bool(jnp.isfinite(loss))
@@ -96,7 +96,7 @@ def test_model_only_tp_mesh_cache_placement():
     """GQA kv fallback of cache_pspecs on a REAL model-only TP mesh: the
     decode cache must be placeable when kv heads don't divide the model
     axis (sequence-dim fallback) and the batch has no data axis to take."""
-    mesh = jax.make_mesh((8,), ("model",))
+    mesh = make_mesh((8,), ("model",))
     cfg = get_config("h2o-danube-1.8b", reduced=True).replace(num_layers=2)
     assert cfg.num_kv_heads == 2                    # 2 % 8 != 0 -> fallback
     model = Model(cfg)
@@ -285,6 +285,46 @@ def test_ep_train_step_end_to_end():
     step = jax.jit(make_train_step(model, opt))
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 64),
                                           0, cfg.vocab_size)}
-    with shd.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         params, state, metrics = step(params, opt.init(params), batch)
     assert bool(jnp.isfinite(metrics["loss"]))
+
+
+def test_flash_kernel_partitions_under_a_mesh():
+    """GSPMD cannot partition a Pallas call: under a multi-device mesh the
+    flash kernels run per batch shard inside a shard_map.  Output and grads
+    must match the tiled jax path on the same sharded inputs."""
+    from repro.kernels import ops as kops
+    mesh = make_debug_mesh(data=4, model=2)
+    keys = jax.random.split(jax.random.PRNGKey(0), 4)
+    q, k, v, ct = (jax.random.normal(kk, (4, 4, 128, 32)) for kk in keys)
+    sharded = NamedSharding(mesh, P("data"))
+    q, k, v, ct = (jax.device_put(a, sharded) for a in (q, k, v, ct))
+
+    def run(impl):
+        def f(q, k, v, ct):
+            out, vjp = jax.vjp(lambda *a: kops.flash_attention_trainable(
+                *a, True, None, None, 64, 64, impl), q, k, v)
+            return (out,) + vjp(ct)
+        with jax.set_mesh(mesh):
+            return jax.jit(f)(q, k, v, ct)
+
+    for a, b in zip(run("pallas"), run("jax")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-5)
+
+
+def test_chip_smoke_ep_parity_reduced():
+    """chip_smoke.py --four-chips at reduced widths, in bf16 on the Zipf
+    data: with a bf16 scatter-add the one-device embedding gradient drifted
+    8% from the FSDP one (frequent tokens' rows stalled); the f32 row sums
+    of ``embed_lookup`` keep every leaf inside the check's limits."""
+    import pathlib
+    import sys
+    sys.path.insert(0, str(pathlib.Path(__file__).resolve().parents[1]))
+    import chip_smoke as cs
+    cfg = _moe_cfg().replace(num_layers=2, dtype="bfloat16",
+                             use_flash_kernel=True, moe_backend="grouped")
+    out = cs.phase_ep_parity(cfg, ep=4, batch=4, seq=1024)
+    assert out["failed"] == [], out
+    assert out["leaves_checked"] > 0 and "['embed']" not in out["noise_leaves"]

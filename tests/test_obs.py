@@ -320,7 +320,8 @@ def test_driver_window_has_throughput_mfu_and_drift(train_run):
     w = [e for e in events if e["kind"] == "train_window"][-1]
     assert w["tokens_per_s"] > 0
     assert w["steady_steps"] >= 1
-    assert 0 < w["mfu"] < 10            # nominal CPU peak: order-of-magnitude
+    # MFU is a device metric: a CPU run has no peak and emits none
+    assert "mfu" not in w and "achieved_flops_per_s" not in w
     assert w["mem_measured_peak_bytes"] > 0
     assert w["mem_predicted_bytes"] > 0
     assert 0.5 <= w["mem_drift_x"] <= 2.0   # acceptance: within 2x

@@ -47,8 +47,7 @@ def test_pallas_interpret_matches_jax_reference(window, softcap):
     ref = pk.paged_attention(q, k, v, pos, pt, t, kv_len=kv_len,
                              window=window, softcap=softcap, impl="jax")
     out = pk.paged_attention(q, k, v, pos, pt, t, kv_len=kv_len,
-                             window=window, softcap=softcap, impl="pallas",
-                             interpret=True)
+                             window=window, softcap=softcap, impl="pallas")
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
 
@@ -88,7 +87,7 @@ def test_unmapped_pages_and_stale_positions_masked():
 
     # pallas path must mask identically
     pal = pk.paged_attention(q, k, v, jnp.asarray(pos2), pt, t,
-                             kv_len=kv_len, impl="pallas", interpret=True)
+                             kv_len=kv_len, impl="pallas")
     np.testing.assert_allclose(np.asarray(pal), np.asarray(base),
                                rtol=2e-5, atol=2e-5)
 

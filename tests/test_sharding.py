@@ -84,9 +84,9 @@ def test_jit_step_on_debug_mesh():
     pspecs = shd.param_pspecs(model.logical_axes(), model.abstract_params(), mesh)
     batch = {"tokens": jax.random.randint(jax.random.PRNGKey(1), (2, 32),
                                           0, cfg.vocab_size)}
-    with shd.use_mesh(mesh):
+    with jax.set_mesh(mesh):
         step = jax.jit(lambda p, b: model.loss(p, b),
-                       in_shardings=(shd.jit_shardings(pspecs, mesh), None))
+                       in_shardings=(pspecs, None))
         loss = step(params, batch)
     assert bool(jnp.isfinite(loss))
 

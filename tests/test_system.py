@@ -9,6 +9,7 @@ import pytest
 
 from repro.configs.base import get_config
 from repro.data.pipeline import DataConfig, eval_batch, packed_batches
+from repro.launch.mesh import make_debug_mesh
 from repro.models.model import Model
 from repro.optim.adamw import AdamW
 from repro.train.driver import RunConfig, elastic_remesh, train
@@ -93,17 +94,16 @@ def test_reversible_residuals_are_depth_independent():
 
 
 def test_elastic_remesh_roundtrip():
-    mesh_a = jax.make_mesh((1, 1), ("data", "model"))
+    mesh_a = make_debug_mesh(1, 1)
     cfg = get_config("h2o-danube-1.8b", reduced=True).replace(num_layers=2)
     model = Model(cfg)
     params = model.init(jax.random.PRNGKey(0))
     opt = AdamW(lr=1e-3)
     st = opt.init(params)
-    mesh_b = jax.make_mesh((1, 1), ("data", "model"))
+    mesh_b = make_debug_mesh(1, 1)
     p2, st2, pspecs = elastic_remesh(params, st, model, mesh_a, mesh_b)
     batch = {"tokens": jnp.zeros((2, 32), jnp.int32)}
-    from repro.distributed import sharding as shd
-    with shd.use_mesh(mesh_b):
+    with jax.set_mesh(mesh_b):
         loss = model.loss(p2, batch)
     assert bool(jnp.isfinite(loss))
 
