@@ -29,6 +29,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs import scopes
+
 
 def coupling(F: Callable, target: int, fp_iters: int = 1):
     """One reversible additive update of a single stream.
@@ -122,6 +124,16 @@ def shared_cotangent(csh, shared):
         csh, shared, is_leaf=lambda x: x is None)
 
 
+def layer_vjp(f: Callable, primals, cts):
+    """Cotangents of ``f``'s inputs: the forward that ``jax.vjp`` linearises
+    runs under ``rev.recompute``, the vjp applied to ``cts`` under
+    ``rev.vjp`` (the per-layer step of every backward walk)."""
+    with jax.named_scope(scopes.REV_RECOMPUTE):
+        _, vjp = jax.vjp(f, *primals)
+    with jax.named_scope(scopes.REV_VJP):
+        return vjp(cts)
+
+
 def reversible_stack(block_fwd: Callable, block_inv: Callable, n_layers: int,
                      save_memory=True, half_inv: Callable = None,
                      idx_offset: int = 0):
@@ -163,10 +175,12 @@ def reversible_stack(block_fwd: Callable, block_inv: Callable, n_layers: int,
 
     @jax.custom_vjp
     def apply(stacked, shared, ctx, x1, x2):
-        return plain(stacked, shared, ctx, x1, x2)
+        with jax.named_scope(scopes.WALK_FORWARD):
+            return plain(stacked, shared, ctx, x1, x2)
 
     def fwd_rule(stacked, shared, ctx, x1, x2):
-        y1, y2 = plain(stacked, shared, ctx, x1, x2)
+        with jax.named_scope(scopes.WALK_FORWARD):
+            y1, y2 = plain(stacked, shared, ctx, x1, x2)
         # residuals: params + OUTPUT only — no per-layer activations
         return (y1, y2), (stacked, shared, ctx, y1, y2)
 
@@ -177,20 +191,21 @@ def reversible_stack(block_fwd: Callable, block_inv: Callable, n_layers: int,
         def body(carry, inp):
             i, lp = inp
             cy1, cy2, c1, c2, csh = carry
-            x1, x2 = block_inv(lp, shared, ctx, i, cy1, cy2)
+            with jax.named_scope(scopes.REV_INVERT):
+                x1, x2 = block_inv(lp, shared, ctx, i, cy1, cy2)
             x1 = jax.lax.stop_gradient(x1)
             x2 = jax.lax.stop_gradient(x2)
-            _, vjp = jax.vjp(
+            dlp, dsh, d1, d2 = layer_vjp(
                 lambda lp_, sh_, a, b: block_fwd(lp_, sh_, ctx, i, a, b),
-                lp, shared, x1, x2)
-            dlp, dsh, d1, d2 = vjp((c1, c2))
+                (lp, shared, x1, x2), (c1, c2))
             return (x1, x2, d1, d2, accumulate_shared(csh, dsh)), dlp
 
         init = (y1, y2, ct1, ct2, zero_shared(shared))
         from repro.core import settings as _s
-        (_, _, d1, d2, dsh), dstacked = jax.lax.scan(
-            body, init, (idxs, stacked), reverse=True,
-            unroll=_s.SCAN_UNROLL)
+        with jax.named_scope(scopes.WALK_UPDATE):
+            (_, _, d1, d2, dsh), dstacked = jax.lax.scan(
+                body, init, (idxs, stacked), reverse=True,
+                unroll=_s.SCAN_UNROLL)
         return (dstacked, shared_cotangent(dsh, shared),
                 _zeros_tangent(ctx), d1, d2)
 
@@ -203,7 +218,8 @@ def _half_stack(block_fwd, half_inv, n_layers, plain, idxs):
 
     @jax.custom_vjp
     def apply(stacked, shared, ctx, x1, x2):
-        return plain(stacked, shared, ctx, x1, x2)
+        with jax.named_scope(scopes.WALK_FORWARD):
+            return plain(stacked, shared, ctx, x1, x2)
 
     def fwd_rule(stacked, shared, ctx, x1, x2):
         from repro.core import settings
@@ -212,8 +228,10 @@ def _half_stack(block_fwd, half_inv, n_layers, plain, idxs):
             i, lp = inp
             a, b = carry
             return block_fwd(lp, shared, ctx, i, a, b), a   # save x1 input
-        (y1, y2), x1_stack = jax.lax.scan(body, (x1, x2), (idxs, stacked),
-                                          unroll=settings.SCAN_UNROLL)
+        with jax.named_scope(scopes.WALK_FORWARD):
+            (y1, y2), x1_stack = jax.lax.scan(body, (x1, x2),
+                                              (idxs, stacked),
+                                              unroll=settings.SCAN_UNROLL)
         return (y1, y2), (stacked, shared, ctx, x1_stack, y1, y2)
 
     def bwd_rule(res, cts):
@@ -226,18 +244,19 @@ def _half_stack(block_fwd, half_inv, n_layers, plain, idxs):
         def body(carry, inp):
             i, lp, x1_k, y1_k = inp
             y2_k, c1, c2, csh = carry
-            x2_k = jax.lax.stop_gradient(
-                half_inv(lp, shared, ctx, i, x1_k, y1_k, y2_k))
-            _, vjp = jax.vjp(
+            with jax.named_scope(scopes.REV_INVERT):
+                x2_k = jax.lax.stop_gradient(
+                    half_inv(lp, shared, ctx, i, x1_k, y1_k, y2_k))
+            dlp, dsh, d1, d2 = layer_vjp(
                 lambda lp_, sh_, a, b: block_fwd(lp_, sh_, ctx, i, a, b),
-                lp, shared, x1_k, x2_k)
-            dlp, dsh, d1, d2 = vjp((c1, c2))
+                (lp, shared, x1_k, x2_k), (c1, c2))
             return (x2_k, d1, d2, accumulate_shared(csh, dsh)), dlp
 
         init = (y2_fin, ct1, ct2, zero_shared(shared))
-        (_, d1, d2, dsh), dstacked = jax.lax.scan(
-            body, init, (idxs, stacked, x1_stack, y1_stack), reverse=True,
-            unroll=settings.SCAN_UNROLL)
+        with jax.named_scope(scopes.WALK_UPDATE):
+            (_, d1, d2, dsh), dstacked = jax.lax.scan(
+                body, init, (idxs, stacked, x1_stack, y1_stack),
+                reverse=True, unroll=settings.SCAN_UNROLL)
         return (dstacked, shared_cotangent(dsh, shared),
                 _zeros_tangent(ctx), d1, d2)
 
@@ -445,14 +464,14 @@ def fused_stack_backward(block_fwd: Callable, block_inv: Callable, policies,
                     lp = read_layer(st, i - idx_offset)
                     ex = None if ext is None else read_layer(ext,
                                                              i - idx_offset)
-                    x1, x2 = block_inv(lp, shared, ctx, i, cy1, cy2)
+                    with jax.named_scope(scopes.REV_INVERT):
+                        x1, x2 = block_inv(lp, shared, ctx, i, cy1, cy2)
                     x1 = jax.lax.stop_gradient(x1)
                     x2 = jax.lax.stop_gradient(x2)
-                    _, vjp = jax.vjp(
+                    dlp, dsh, d1, d2 = layer_vjp(
                         lambda lp_, sh_, a, b:
                         block_fwd(lp_, sh_, ctx, i, a, b),
-                        lp, shared, x1, x2)
-                    dlp, dsh, d1, d2 = vjp((cc1, cc2))
+                        (lp, shared, x1, x2), (cc1, cc2))
                     st, new_ex, st_stat, csh_ = consume_write(
                         i, lp, dlp, st, ex, st_stat, csh_, dsh)
                     if new_ex is not None:
@@ -475,11 +494,10 @@ def fused_stack_backward(block_fwd: Callable, block_inv: Callable, policies,
                     lp = read_layer(st, i - idx_offset)
                     ex = None if ext is None else read_layer(ext,
                                                              i - idx_offset)
-                    _, vjp = jax.vjp(
+                    dlp, dsh, d1, d2 = layer_vjp(
                         lambda lp_, sh_, a_, b_:
                         block_fwd(lp_, sh_, ctx, i, a_, b_),
-                        lp, shared, a, b)
-                    dlp, dsh, d1, d2 = vjp((cc1, cc2))
+                        (lp, shared, a, b), (cc1, cc2))
                     st, new_ex, st_stat, csh_ = consume_write(
                         i, lp, dlp, st, ex, st_stat, csh_, dsh)
                     if new_ex is not None:
@@ -538,8 +556,8 @@ def _grouped_vjp(block_fwd, layout, gp, shared, ctx, i, x1, x2, cts):
     def f(b_, d_, p_, sh_, a, b):
         return block_fwd(materialize_unit(b_, d_, p_), sh_, ctx, i, a, b)
 
-    _, vjp = jax.vjp(f, b_sl, d_sl, p_sl, shared, x1, x2)
-    db, dd, dp, dsh, d1, d2 = vjp(cts)
+    db, dd, dp, dsh, d1, d2 = layer_vjp(
+        f, (b_sl, d_sl, p_sl, shared, x1, x2), cts)
     return g, (b_sl, d_sl, p_sl), (db, dd, dp), dsh, (d1, d2)
 
 
@@ -584,10 +602,12 @@ def grouped_reversible_stack(block_fwd: Callable, block_inv: Callable,
 
     @jax.custom_vjp
     def apply(gp, shared, ctx, x1, x2):
-        return plain(gp, shared, ctx, x1, x2)
+        with jax.named_scope(scopes.WALK_FORWARD):
+            return plain(gp, shared, ctx, x1, x2)
 
     def fwd_rule(gp, shared, ctx, x1, x2):
-        y1, y2 = plain(gp, shared, ctx, x1, x2)
+        with jax.named_scope(scopes.WALK_FORWARD):
+            y1, y2 = plain(gp, shared, ctx, x1, x2)
         return (y1, y2), (gp, shared, ctx, y1, y2)
 
     def bwd_rule(res, cts):
@@ -596,8 +616,9 @@ def grouped_reversible_stack(block_fwd: Callable, block_inv: Callable,
 
         def body(carry, i):
             cy1, cy2, c1, c2, dgp, csh = carry
-            lp = read_unit(layout, gp, i)
-            x1, x2 = block_inv(lp, shared, ctx, i, cy1, cy2)
+            with jax.named_scope(scopes.REV_INVERT):
+                lp = read_unit(layout, gp, i)
+                x1, x2 = block_inv(lp, shared, ctx, i, cy1, cy2)
             x1 = jax.lax.stop_gradient(x1)
             x2 = jax.lax.stop_gradient(x2)
             g, _, (db, dd, dp), dsh, (d1, d2) = _grouped_vjp(
@@ -609,8 +630,9 @@ def grouped_reversible_stack(block_fwd: Callable, block_inv: Callable,
 
         from repro.core import settings as _s
         init = (y1, y2, ct1, ct2, _zeros_grouped(gp), zero_shared(shared))
-        (_, _, d1, d2, dgp, dsh), _ = jax.lax.scan(
-            body, init, idxs, reverse=True, unroll=_s.SCAN_UNROLL)
+        with jax.named_scope(scopes.WALK_UPDATE):
+            (_, _, d1, d2, dgp, dsh), _ = jax.lax.scan(
+                body, init, idxs, reverse=True, unroll=_s.SCAN_UNROLL)
         return (dgp, shared_cotangent(dsh, shared),
                 _zeros_tangent(ctx), d1, d2)
 
@@ -751,8 +773,9 @@ def grouped_fused_stack_backward(block_fwd: Callable, block_inv: Callable,
                 def body(carry, i):
                     cy1, cy2, cc1, cc2, gp_, ext, acc_b, st_stat, csh_ = \
                         carry
-                    lp = read_unit(layout, gp_, i)
-                    x1, x2 = block_inv(lp, shared, ctx, i, cy1, cy2)
+                    with jax.named_scope(scopes.REV_INVERT):
+                        lp = read_unit(layout, gp_, i)
+                        x1, x2 = block_inv(lp, shared, ctx, i, cy1, cy2)
                     x1 = jax.lax.stop_gradient(x1)
                     x2 = jax.lax.stop_gradient(x2)
                     gp_, ext, acc_b, st_stat, csh_, d1, d2 = layer_step(

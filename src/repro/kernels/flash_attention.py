@@ -154,6 +154,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
             pltpu.VMEM((bq, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qr, kr, vr)
     out = out.reshape(B, H, Sq, hd)
     if return_lse:
@@ -308,6 +309,7 @@ def flash_attention_bwd(q, k, v, lse, delta, do, *, causal: bool = True,
         out_shape=jax.ShapeDtypeStruct((B * H, Sq, hd), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, hd), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(qr, kr, vr, dor, lser, deltar)
 
     # group-major layouts so the dk/dv grid walks (g, iq) innermost
@@ -347,6 +349,7 @@ def flash_attention_bwd(q, k, v, lse, delta, do, *, causal: bool = True,
             pltpu.VMEM((bk, hd), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_dkv",
     )(qg, kr, vr, dog, lseg, deltag)
 
     return (dq.reshape(B, H, Sq, hd),

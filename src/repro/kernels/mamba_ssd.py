@@ -84,5 +84,6 @@ def mamba_ssd(x, B_t, C_t, dt, log_a, *, chunk: int = 128,
         out_shape=jax.ShapeDtypeStruct((Bb * H, S, P), x.dtype),
         scratch_shapes=[pltpu.VMEM((N, P), jnp.float32)],
         interpret=interpret,
+        name="mamba_ssd",
     )(xr, B_t, C_t, dtr, lar)
     return out.reshape(Bb, H, S, P)

@@ -70,4 +70,5 @@ def grouped_matmul_pallas(lhs, rhs, tile_expert, *, block_m: int,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m_pad, N), lhs.dtype),
         interpret=interpret,
+        name="gmm",
     )(tile_expert.astype(jnp.int32), lhs, rhs)

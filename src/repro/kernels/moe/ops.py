@@ -33,6 +33,7 @@ import numpy as np
 from repro.kernels.moe import dispatch as dsp
 from repro.kernels.moe.grouped_gemm import grouped_matmul_pallas
 from repro.kernels.moe.ref import grouped_matmul_ref
+from repro.obs import scopes
 
 IMPLS = ("pallas", "jax")
 
@@ -101,10 +102,13 @@ def grouped_expert_ffn(x, expert_idx, gates, w_gate, w_up, w_down, *,
     block_m = block_m or default_block_m()
     impl = impl or default_impl()
     num_tokens = x.shape[0]
-    plan = dsp.make_plan(expert_idx, w_gate.shape[0], block_m)
-    xs = dsp.permute(x, plan)
-    g = grouped_matmul(xs, w_gate, plan.tile_expert, block_m, impl)
-    u = grouped_matmul(xs, w_up, plan.tile_expert, block_m, impl)
-    h = jax.nn.silu(g) * u
-    ys = grouped_matmul(h, w_down, plan.tile_expert, block_m, impl)
-    return dsp.combine(ys, gates, plan, num_tokens)
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        plan = dsp.make_plan(expert_idx, w_gate.shape[0], block_m)
+        xs = dsp.permute(x, plan)
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        g = grouped_matmul(xs, w_gate, plan.tile_expert, block_m, impl)
+        u = grouped_matmul(xs, w_up, plan.tile_expert, block_m, impl)
+        h = jax.nn.silu(g) * u
+        ys = grouped_matmul(h, w_down, plan.tile_expert, block_m, impl)
+    with jax.named_scope(scopes.MOE_COMBINE):
+        return dsp.combine(ys, gates, plan, num_tokens)

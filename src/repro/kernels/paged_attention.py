@@ -199,6 +199,7 @@ def paged_attention_pallas(q, k_pool, v_pool, pos_pool, page_table, t, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )(page_table, t, q, k_pool, v_pool, pos_pool.reshape(P, 1, page))
     return out.reshape(B, H * hd)
 

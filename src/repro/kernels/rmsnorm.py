@@ -42,6 +42,7 @@ def rmsnorm(x, scale, *, eps: float = 1e-6, block_rows: int = 128,
         out_specs=pl.BlockSpec((br, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
         interpret=interpret,
+        name="rmsnorm",
     )(xr, scale)
     if pad:
         out = out[:n]

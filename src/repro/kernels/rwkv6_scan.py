@@ -92,5 +92,6 @@ def rwkv6_scan(r, k, v, w, u, *, chunk: int = 64, interpret: bool):
         out_shape=jax.ShapeDtypeStruct((B * H, S, C), r.dtype),
         scratch_shapes=[pltpu.VMEM((C, C), jnp.float32)],
         interpret=interpret,
+        name="rwkv6_scan",
     )(rr, kk, vv, ll, uu)
     return out.reshape(B, H, S, C)

@@ -14,7 +14,7 @@
 
 ``summarize`` renders p50/p99 tables from the raw events (exact, not the
 bucket-resolution registry histograms): train step time / loss trajectory /
-throughput + MFU + memory drift, per-layer reversible-audit attribution and
+throughput + memory drift, per-layer reversible-audit attribution and
 MoE routing telemetry, serving TTFT / TPOT / queue wait, span durations,
 compiles and checkpoint I/O.  ``validate`` applies the schema gates CI runs
 (see repro.obs.sink.validate_events); ``--max-reconstruction-err`` bounds
@@ -121,8 +121,7 @@ def summarize(events: List[dict]) -> None:
     if wins:
         last = wins[-1]
         print(f"  last window: {_fmt(last.get('steps_per_s'))} steps/s, "
-              f"{_fmt(last.get('tokens_per_s'))} tok/s, "
-              f"mfu {_fmt(last.get('mfu'))}")
+              f"{_fmt(last.get('tokens_per_s'))} tok/s")
         if last.get("mem_measured_peak_bytes") is not None:
             print(f"  memory: measured peak "
                   f"{_fmt(last['mem_measured_peak_bytes'], 'GiB')} vs "
@@ -273,7 +272,7 @@ def export_chrome_trace(events: List[dict], out_path: str) -> int:
                           "args": {k: v for k, v in ev.items()
                                    if k not in ("v", "kind", "ts")}})
         elif kind == "train_window":
-            for key in ("steps_per_s", "tokens_per_s", "mfu", "mem_drift_x"):
+            for key in ("steps_per_s", "tokens_per_s", "mem_drift_x"):
                 if ev.get(key) is not None:
                     trace.append({"ph": "C", "pid": pid, "name": key,
                                   "ts": ts_us, "args": {key: ev[key]}})

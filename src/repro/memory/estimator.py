@@ -455,36 +455,9 @@ def kv_page_cost(cfg: ModelConfig, page_size: int = 16,
     }
 
 
-#: train-step cost multiplier over forward FLOPs per activation policy
-#: (benchmarks/roofline.py's accounting: standard fwd+bwd = 3x fwd, remat
-#: re-runs forward = 4x, reversible adds inverse + re-linearise = 5x;
-#: offload moves bytes, not FLOPs, so it costs like store)
-TRAIN_FLOP_MULT = {"store": 3.0, "offload": 3.0, "remat": 4.0,
-                   "reversible": 5.0}
-
-
-def train_step_flops(model, batch: int, seq: int, save_memory=True) -> float:
-    """Achieved-FLOPs model for one optimizer step at (batch, seq) — the
-    numerator of the MFU gauge (repro.obs).  Forward is the standard
-    ``2 * n_params * tokens`` dense-equivalent (MoE expert params are all
-    counted: an upper bound that makes MFU conservative), scaled by the
-    per-policy train multiplier — averaged across units for a mixed plan."""
-    tokens = batch * seq
-    fwd = 2.0 * model.num_params() * tokens
-    cfg = model.cfg
-    if isinstance(save_memory, (list, tuple)):
-        mults = [TRAIN_FLOP_MULT.get(p, 3.0) for p in save_memory]
-        mult = sum(mults) / max(len(mults), 1)
-    elif save_memory and cfg.reversible:
-        mult = TRAIN_FLOP_MULT["reversible"]
-    else:
-        mult = TRAIN_FLOP_MULT["store"]
-    return mult * fwd
-
-
 @dataclasses.dataclass(frozen=True)
 class DevicePeaks:
-    """Published per-chip peaks: the MFU and roofline denominators."""
+    """Published per-chip peaks: utilisation and roofline denominators."""
     bf16_flops_per_s: float
     hbm_bytes_per_s: float
     source: str

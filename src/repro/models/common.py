@@ -12,16 +12,18 @@ import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.models.spec import ParamSpec
+from repro.obs import scopes
 
 
 # ---------------------------------------------------------------- norms
 
 def rms_norm(x, scale, eps: float = 1e-6):
-    dtype = x.dtype
-    x = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
-    out = x * jax.lax.rsqrt(var + eps) * (1.0 + scale.astype(jnp.float32))
-    return out.astype(dtype)
+    with jax.named_scope(scopes.NORM):
+        dtype = x.dtype
+        x = x.astype(jnp.float32)
+        var = jnp.mean(jnp.square(x), axis=-1, keepdims=True)
+        out = x * jax.lax.rsqrt(var + eps) * (1.0 + scale.astype(jnp.float32))
+        return out.astype(dtype)
 
 
 def embed_lookup(table, tokens):

@@ -38,6 +38,7 @@ from repro.models.common import (attention, attention_decode, attn_specs,
                                  embed_lookup, init_kv_cache, lm_head_logits,
                                  mlp, mlp_specs, norm_spec, rms_norm, softcap)
 from repro.models.spec import ParamSpec
+from repro.obs import scopes
 
 BIG_WINDOW = 1 << 30
 
@@ -106,18 +107,21 @@ def _attn_F(cfg: ModelConfig, window_fn, causal=True):
         n1 = rms_norm(x1, p["norm1"], cfg.norm_eps)
         n2 = rms_norm(x2, p["norm2"], cfg.norm_eps)
         win = window_fn(i) if window_fn else None
-        if cfg.fold_adapters:
-            eff = _fold_attn(p["attn_ad"], p["attn"])
-            return attention(eff, cfg, _act_constrain(n1), _act_constrain(n2),
-                             positions_q=ctx["positions"],
-                             positions_k=ctx["positions"],
-                             causal=causal, window=win)
-        q_in = _up(p["attn_ad"], n1)
-        kv_in = _up(p["attn_ad"], n2)
-        att = attention(p["attn"], cfg, q_in, kv_in,
-                        positions_q=ctx["positions"], positions_k=ctx["positions"],
-                        causal=causal, window=win)
-        return _down(p["attn_ad"], att)
+        with jax.named_scope(scopes.ATTN):
+            if cfg.fold_adapters:
+                eff = _fold_attn(p["attn_ad"], p["attn"])
+                return attention(eff, cfg, _act_constrain(n1),
+                                 _act_constrain(n2),
+                                 positions_q=ctx["positions"],
+                                 positions_k=ctx["positions"],
+                                 causal=causal, window=win)
+            q_in = _up(p["attn_ad"], n1)
+            kv_in = _up(p["attn_ad"], n2)
+            att = attention(p["attn"], cfg, q_in, kv_in,
+                            positions_q=ctx["positions"],
+                            positions_k=ctx["positions"],
+                            causal=causal, window=win)
+            return _down(p["attn_ad"], att)
     return F
 
 
@@ -125,13 +129,14 @@ def _mlp_G(cfg: ModelConfig):
     """Paper Eq. 2: FFN driven by the updated left stream."""
     def G(p, sh, ctx, i, y1, _y2=None):
         h = rms_norm(y1, p["norm_mlp"], cfg.norm_eps)
-        if cfg.fold_adapters:
-            pu, pd = p["mlp_ad"]["p_up"], p["mlp_ad"]["p_down"]
-            eff = {"w_gate": pu @ p["mlp"]["w_gate"],
-                   "w_up": pu @ p["mlp"]["w_up"],
-                   "w_down": p["mlp"]["w_down"] @ pd}
-            return mlp(eff, _act_constrain(h))
-        return _down(p["mlp_ad"], mlp(p["mlp"], _up(p["mlp_ad"], h)))
+        with jax.named_scope(scopes.MLP):
+            if cfg.fold_adapters:
+                pu, pd = p["mlp_ad"]["p_up"], p["mlp_ad"]["p_down"]
+                eff = {"w_gate": pu @ p["mlp"]["w_gate"],
+                       "w_up": pu @ p["mlp"]["w_up"],
+                       "w_down": p["mlp"]["w_down"] @ pd}
+                return mlp(eff, _act_constrain(h))
+            return _down(p["mlp_ad"], mlp(p["mlp"], _up(p["mlp_ad"], h)))
     return G
 
 
@@ -191,15 +196,18 @@ def _std_block(cfg: ModelConfig, use_moe: bool):
 
     def fwd(p, sh, ctx, i, h):
         a_in = rms_norm(h, p["norm1"], cfg.norm_eps)
-        att = attention(p["attn"], cfg, a_in, a_in,
-                        positions_q=ctx["positions"], positions_k=ctx["positions"],
-                        causal=True, window=window_fn(i) if window_fn else None)
+        with jax.named_scope(scopes.ATTN):
+            att = attention(p["attn"], cfg, a_in, a_in,
+                            positions_q=ctx["positions"],
+                            positions_k=ctx["positions"], causal=True,
+                            window=window_fn(i) if window_fn else None)
         h = h + att
         m_in = rms_norm(h, p["norm_mlp"], cfg.norm_eps)
         if use_moe:
             y, _ = moe_lib.moe_apply(p["moe"], cfg, m_in)
         else:
-            y = mlp(p["mlp"], m_in)
+            with jax.named_scope(scopes.MLP):
+                y = mlp(p["mlp"], m_in)
         return h + y
     return fwd
 
@@ -369,25 +377,20 @@ def build_zamba(cfg: ModelConfig):
     def attn_F(p, sh, ctx, i, x1, x2):
         n1 = rms_norm(x1, p["norm1"], cfg.norm_eps)
         n2 = rms_norm(x2, p["norm2"], cfg.norm_eps)
-        if cfg.fold_adapters:
-            eff = _fold_attn(p["attn_ad"], p["attn"])
-            return attention(eff, cfg, _act_constrain(n1), _act_constrain(n2),
-                             positions_q=ctx["positions"],
-                             positions_k=ctx["positions"])
-        att = attention(p["attn"], cfg, _up(p["attn_ad"], n1),
-                        _up(p["attn_ad"], n2),
-                        positions_q=ctx["positions"], positions_k=ctx["positions"])
-        return _down(p["attn_ad"], att)
+        with jax.named_scope(scopes.ATTN):
+            if cfg.fold_adapters:
+                eff = _fold_attn(p["attn_ad"], p["attn"])
+                return attention(eff, cfg, _act_constrain(n1),
+                                 _act_constrain(n2),
+                                 positions_q=ctx["positions"],
+                                 positions_k=ctx["positions"])
+            att = attention(p["attn"], cfg, _up(p["attn_ad"], n1),
+                            _up(p["attn_ad"], n2),
+                            positions_q=ctx["positions"],
+                            positions_k=ctx["positions"])
+            return _down(p["attn_ad"], att)
 
-    def mlp_G(p, sh, ctx, i, y1, _=None):
-        h = rms_norm(y1, p["norm_mlp"], cfg.norm_eps)
-        if cfg.fold_adapters:
-            pu, pd = p["mlp_ad"]["p_up"], p["mlp_ad"]["p_down"]
-            eff = {"w_gate": pu @ p["mlp"]["w_gate"],
-                   "w_up": pu @ p["mlp"]["w_up"],
-                   "w_down": p["mlp"]["w_down"] @ pd}
-            return mlp(eff, _act_constrain(h))
-        return _down(p["mlp_ad"], mlp(p["mlp"], _up(p["mlp_ad"], h)))
+    mlp_G = _mlp_G(cfg)
 
     def unit_fwd(lp, sh, ctx, i, x1, x2):
         for j in range(k):
@@ -498,19 +501,22 @@ def build_encdec(cfg: ModelConfig):
     selfF = _attn_F(cfg, None, causal=True)
 
     def crossF(p, sh, ctx, i, y1, x2):      # target 2; reads y1 + encoder output
-        q_in = _up(p["cross_ad"], rms_norm(y1, p["norm_cross"], cfg.norm_eps))
+        n = rms_norm(y1, p["norm_cross"], cfg.norm_eps)
         enc = sh["enc"]
-        att = attention(p["cross"], cfg, q_in, enc,
-                        positions_q=ctx["positions"],
-                        positions_k=jnp.broadcast_to(
-                            jnp.arange(enc.shape[1], dtype=jnp.int32)[None],
-                            enc.shape[:2]),
-                        causal=False, use_rope=False)
-        return _down(p["cross_ad"], att)
+        with jax.named_scope(scopes.ATTN):
+            att = attention(p["cross"], cfg, _up(p["cross_ad"], n), enc,
+                            positions_q=ctx["positions"],
+                            positions_k=jnp.broadcast_to(
+                                jnp.arange(enc.shape[1],
+                                           dtype=jnp.int32)[None],
+                                enc.shape[:2]),
+                            causal=False, use_rope=False)
+            return _down(p["cross_ad"], att)
 
     def mlpF(p, sh, ctx, i, x1, y2):        # target 1; reads y2
-        h = _up(p["mlp_ad"], rms_norm(y2, p["norm_mlp"], cfg.norm_eps))
-        return _down(p["mlp_ad"], mlp(p["mlp"], h))
+        h = rms_norm(y2, p["norm_mlp"], cfg.norm_eps)
+        with jax.named_scope(scopes.MLP):
+            return _down(p["mlp_ad"], mlp(p["mlp"], _up(p["mlp_ad"], h)))
 
     dec_fwd, dec_inv = chain(coupling(selfF, 1, cfg.inverse_fp_iters),
                              coupling(crossF, 2, 1),
@@ -563,15 +569,18 @@ def build_vlm(cfg: ModelConfig):
                                         fp_iters=cfg.inverse_fp_iters)
 
     def crossF(p, sh, ctx, i, x1, x2):      # target 1; reads x2 + image feats
-        q_in = _up(p["cross_ad"], rms_norm(x2, p["norm_cross"], cfg.norm_eps))
+        n = rms_norm(x2, p["norm_cross"], cfg.norm_eps)
         img = sh["img"]
-        att = attention(p["cross"], cfg, q_in, img,
-                        positions_q=ctx["positions"],
-                        positions_k=jnp.broadcast_to(
-                            jnp.arange(img.shape[1], dtype=jnp.int32)[None],
-                            img.shape[:2]),
-                        causal=False, use_rope=False)
-        return jnp.tanh(p["cross_gate"]).astype(att.dtype) * _down(p["cross_ad"], att)
+        with jax.named_scope(scopes.ATTN):
+            att = attention(p["cross"], cfg, _up(p["cross_ad"], n), img,
+                            positions_q=ctx["positions"],
+                            positions_k=jnp.broadcast_to(
+                                jnp.arange(img.shape[1],
+                                           dtype=jnp.int32)[None],
+                                img.shape[:2]),
+                            causal=False, use_rope=False)
+            return (jnp.tanh(p["cross_gate"]).astype(att.dtype)
+                    * _down(p["cross_ad"], att))
 
     cross_fwd, cross_inv = coupling(crossF, 1, 1)
 

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ModelConfig
 from repro.models.spec import ParamSpec
+from repro.obs import scopes
 
 EP_PAD = 16
 GROUP = 512
@@ -112,42 +113,49 @@ def _einsum_dispatch(p, cfg: ModelConfig, xf, probs, gate_vals, expert_idx,
     pad = (-T) % g_size
     G = (T + pad) // g_size
 
-    xg = _pad_rows(xf, pad).reshape(G, g_size, d)
-    probs_g = _pad_rows(probs, pad).reshape(G, g_size, E)
-    gate_g = _pad_rows(gate_vals, pad).reshape(G, g_size, k)
-    idx_g = _pad_rows(expert_idx, pad).reshape(G, g_size, k)
-    valid = _pad_rows(jnp.ones((T,), jnp.float32), pad).reshape(G, g_size)
+    with jax.named_scope(scopes.MOE_DISPATCH):
+        xg = _pad_rows(xf, pad).reshape(G, g_size, d)
+        probs_g = _pad_rows(probs, pad).reshape(G, g_size, E)
+        gate_g = _pad_rows(gate_vals, pad).reshape(G, g_size, k)
+        idx_g = _pad_rows(expert_idx, pad).reshape(G, g_size, k)
+        valid = _pad_rows(jnp.ones((T,), jnp.float32), pad).reshape(G, g_size)
 
-    # position-in-expert with top-k priority (k-major within token order)
-    onehot = jax.nn.one_hot(idx_g, E, dtype=jnp.float32)     # (G, t, k, E)
-    onehot = onehot * valid[..., None, None]                 # pads route nowhere
-    flat = onehot.transpose(0, 2, 1, 3).reshape(G, k * g_size, E)  # k-major
-    pos = jnp.cumsum(flat, axis=1) - flat                    # (G, k*t, E)
-    C = _capacity(g_size, E, k, cfg.capacity_factor)
-    keep = (pos < C) * flat
-    pos_oh = jax.nn.one_hot(pos, C, dtype=jnp.float32) * keep[..., None]
-    # back to token-major: (G, k, t, E, C) -> sum over k
-    pos_oh = pos_oh.reshape(G, k, g_size, E, C)
-    dispatch = jnp.sum(pos_oh, axis=1)                       # (G, t, E, C) 0/1
-    gates_te = jnp.einsum("gtke,gtk->gte",
-                          onehot * keep.reshape(G, k, g_size, E).transpose(0, 2, 1, 3),
-                          gate_g)
-    combine = dispatch * gates_te[..., None]                 # (G, t, E, C)
+        # position-in-expert with top-k priority (k-major within token order)
+        onehot = jax.nn.one_hot(idx_g, E, dtype=jnp.float32)  # (G, t, k, E)
+        onehot = onehot * valid[..., None, None]             # pads route nowhere
+        flat = onehot.transpose(0, 2, 1, 3).reshape(G, k * g_size, E)
+        pos = jnp.cumsum(flat, axis=1) - flat                # (G, k*t, E)
+        C = _capacity(g_size, E, k, cfg.capacity_factor)
+        keep = (pos < C) * flat
+        pos_oh = jax.nn.one_hot(pos, C, dtype=jnp.float32) * keep[..., None]
+        # back to token-major: (G, k, t, E, C) -> sum over k
+        pos_oh = pos_oh.reshape(G, k, g_size, E, C)
+        dispatch = jnp.sum(pos_oh, axis=1)                   # (G, t, E, C) 0/1
+        expert_in = jnp.einsum("gtec,gtd->gecd", dispatch.astype(xf.dtype),
+                               xg)
 
-    # dispatch -> expert compute -> combine
-    expert_in = jnp.einsum("gtec,gtd->gecd", dispatch.astype(xf.dtype), xg)
-    h = jax.nn.silu(jnp.einsum("gecd,edf->gecf", expert_in, p["w_gate"])) * \
-        jnp.einsum("gecd,edf->gecf", expert_in, p["w_up"])
-    expert_out = jnp.einsum("gecf,efd->gecd", h, p["w_down"])
-    y = jnp.einsum("gtec,gecd->gtd", combine.astype(xf.dtype), expert_out)
-    y = y.reshape(G * g_size, d)[:T]
+    with jax.named_scope(scopes.MOE_EXPERTS):
+        h = jax.nn.silu(jnp.einsum("gecd,edf->gecf", expert_in,
+                                   p["w_gate"])) * \
+            jnp.einsum("gecd,edf->gecf", expert_in, p["w_up"])
+        expert_out = jnp.einsum("gecf,efd->gecd", h, p["w_down"])
+
+    with jax.named_scope(scopes.MOE_COMBINE):
+        gates_te = jnp.einsum(
+            "gtke,gtk->gte",
+            onehot * keep.reshape(G, k, g_size, E).transpose(0, 2, 1, 3),
+            gate_g)
+        combine = dispatch * gates_te[..., None]             # (G, t, E, C)
+        y = jnp.einsum("gtec,gecd->gtd", combine.astype(xf.dtype), expert_out)
+        y = y.reshape(G * g_size, d)[:T]
 
     # load-balancing aux loss (Switch): E * mean_g(sum_e frac_e * mean_prob_e),
     # masked so pad slots do not dilute the per-group statistics
-    n_valid = jnp.sum(valid, axis=1)                         # (G,) >= 1
-    frac = jnp.sum(jnp.sum(onehot, axis=2), axis=1) / n_valid[:, None]
-    mean_p = jnp.sum(probs_g * valid[..., None], axis=1) / n_valid[:, None]
-    aux = cfg.num_experts * jnp.mean(jnp.sum(frac * mean_p, axis=-1))
+    with jax.named_scope(scopes.MOE_ROUTER):
+        n_valid = jnp.sum(valid, axis=1)                     # (G,) >= 1
+        frac = jnp.sum(jnp.sum(onehot, axis=2), axis=1) / n_valid[:, None]
+        mean_p = jnp.sum(probs_g * valid[..., None], axis=1) / n_valid[:, None]
+        aux = cfg.num_experts * jnp.mean(jnp.sum(frac * mean_p, axis=-1))
     return y, aux.astype(jnp.float32)
 
 
@@ -213,9 +221,11 @@ def _switch_aux(cfg: ModelConfig, probs, expert_idx):
     """Global (ungrouped) Switch load-balancing statistic, shared by the
     grouped and expert-parallel dispatch paths."""
     E = padded_experts(cfg.num_experts)
-    frac = jnp.mean(jnp.sum(jax.nn.one_hot(expert_idx, E, dtype=jnp.float32),
-                            axis=1), axis=0)                 # (E,)
-    aux = cfg.num_experts * jnp.sum(frac * jnp.mean(probs, axis=0))
+    with jax.named_scope(scopes.MOE_ROUTER):
+        frac = jnp.mean(jnp.sum(jax.nn.one_hot(expert_idx, E,
+                                               dtype=jnp.float32),
+                                axis=1), axis=0)             # (E,)
+        aux = cfg.num_experts * jnp.sum(frac * jnp.mean(probs, axis=0))
     return aux.astype(jnp.float32)
 
 
@@ -265,7 +275,8 @@ def moe_apply(p, cfg: ModelConfig, x, *, group: Optional[int] = None,
     assert backend in MOE_BACKENDS, backend
     xf = x.reshape(T, d)
 
-    probs, gate_vals, expert_idx = _route(p, cfg, xf)
+    with jax.named_scope(scopes.MOE_ROUTER):
+        probs, gate_vals, expert_idx = _route(p, cfg, xf)
     if cfg.expert_parallel > 0:
         y, aux = _ep_dispatch(p, cfg, xf, probs, gate_vals, expert_idx)
     elif backend == "grouped":
@@ -278,11 +289,12 @@ def moe_apply(p, cfg: ModelConfig, x, *, group: Optional[int] = None,
 
     if "shared" in p:
         sh = p["shared"]
-        hs = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, sh["w_gate"])) * \
-             jnp.einsum("bsd,df->bsf", x, sh["w_up"])
-        ys = jnp.einsum("bsf,fd->bsd", hs, sh["w_down"])
-        sgate = jax.nn.sigmoid(jnp.einsum("bsd,do->bso", x, sh["gate"]))
-        y = y + sgate.astype(y.dtype) * ys
+        with jax.named_scope(scopes.MOE_SHARED):
+            hs = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, sh["w_gate"])) * \
+                jnp.einsum("bsd,df->bsf", x, sh["w_up"])
+            ys = jnp.einsum("bsf,fd->bsd", hs, sh["w_down"])
+            sgate = jax.nn.sigmoid(jnp.einsum("bsd,do->bso", x, sh["gate"]))
+            y = y + sgate.astype(y.dtype) * ys
     return y, aux
 
 

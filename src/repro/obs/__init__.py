@@ -78,19 +78,23 @@ class Telemetry:
         callable returning one) is passed to ``jax.block_until_ready`` at
         exit so the span covers device execution, not just dispatch; without
         it the span measures host wall time of the block.  The duration also
-        lands in the ``span.<name>`` histogram unless ``observe=False``."""
+        lands in the ``span.<name>`` histogram unless ``observe=False``.
+        The block also runs inside ``jax.profiler.TraceAnnotation(name)``,
+        so a profiler trace shows the span on its host timeline."""
+        import jax
         sp = Span(name=name, t0=time.time(), **labels)
         t0 = time.perf_counter()
-        try:
-            yield sp
-        finally:
-            if fence is not None:
-                import jax
-                jax.block_until_ready(fence() if callable(fence) else fence)
-            sp["dur_s"] = time.perf_counter() - t0
-            self.emit("span", **sp)
-            if observe:
-                self.histogram(f"span.{name}").observe(sp["dur_s"])
+        with jax.profiler.TraceAnnotation(name):
+            try:
+                yield sp
+            finally:
+                if fence is not None:
+                    jax.block_until_ready(fence() if callable(fence)
+                                          else fence)
+                sp["dur_s"] = time.perf_counter() - t0
+                self.emit("span", **sp)
+                if observe:
+                    self.histogram(f"span.{name}").observe(sp["dur_s"])
 
     def flush_metrics(self, **labels) -> dict:
         """Emit a full registry snapshot as a ``metrics`` event."""

@@ -11,6 +11,7 @@ PartitionSpecs, reshard live state with jax.device_put, re-jit the step.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Callable, Optional
@@ -79,8 +80,9 @@ def train(model, optimizer, data_cfg: DataConfig, run: RunConfig,
     list): the step then runs the planned mixed activation policies instead
     of the all-reversible default.  ``telemetry`` is a JSONL path or a
     ``repro.obs.Telemetry``: the driver then emits per-step loss/grad-norm/
-    step-time events, per-window throughput + MFU + estimator-drift gauges,
-    and checkpoint/compile durations (DESIGN.md §11).  With
+    step-time events, per-window throughput + estimator-drift gauges, and
+    checkpoint/compile durations (DESIGN.md §11), and marks each step on a
+    profiler trace's timeline (``jax.profiler.StepTraceAnnotation``).  With
     ``run.audit_every > 0`` (and live telemetry) every Nth step additionally
     runs the reversible audit (repro.obs.audit): per-layer reconstruction
     error, per-policy backward-time/residual-byte attribution, and MoE
@@ -137,16 +139,8 @@ def train(model, optimizer, data_cfg: DataConfig, run: RunConfig,
 
     tokens_per_step = data_cfg.global_batch * data_cfg.seq_len
     micro_b = max(data_cfg.global_batch // run.n_micro, 1)
-    flops_per_step = peak = None
     memw = None
     if tel.enabled:
-        if jax.devices()[0].platform != "cpu":
-            # MFU is a device metric: a CPU run reports counts only, and an
-            # accelerator without a published peak is an error, not a guess
-            from repro.memory import estimator as est
-            flops_per_step = est.train_step_flops(
-                model, data_cfg.global_batch, data_cfg.seq_len, save_memory)
-            peak = est.device_peaks().bf16_flops_per_s
         memw = obs.MemoryWatchdog(tel, _predicted_peak_bytes(
             model, optimizer, micro_b, data_cfg.seq_len, save_memory,
             fused=run.fused_optimizer))
@@ -166,10 +160,6 @@ def train(model, optimizer, data_cfg: DataConfig, run: RunConfig,
                "loss_mean": float(np.mean(losses[-run.log_every:])),
                "steps_per_s": sps, "steady_steps": window_steps,
                "tokens_per_s": sps * tokens_per_step}
-        if flops_per_step is not None:
-            win["achieved_flops_per_s"] = sps * flops_per_step
-            win["mfu"] = sps * flops_per_step / peak
-            tel.gauge("train.mfu").set(win["mfu"])
         tel.gauge("train.tokens_per_s").set(win["tokens_per_s"])
         if memw is not None:
             win.update(memw.window_fields())
@@ -181,9 +171,12 @@ def train(model, optimizer, data_cfg: DataConfig, run: RunConfig,
     for step in range(start_step, run.total_steps):
         batch = next(it)
         fn = step1 if step < run.stage1_steps else step2
+        mark = (jax.profiler.StepTraceAnnotation("train", step_num=step)
+                if tel.enabled else contextlib.nullcontext())
         t_st = time.perf_counter()
-        params, opt_state, metrics = fn(params, opt_state, batch)
-        loss = float(metrics["loss"])           # host sync: step is done
+        with mark:
+            params, opt_state, metrics = fn(params, opt_state, batch)
+            loss = float(metrics["loss"])       # host sync: step is done
         dt = time.perf_counter() - t_st
         losses.append(loss)
         compiled = fn.last_call_compiled

@@ -9,23 +9,25 @@ to the optimizer the moment it exists, inside the backward scan
 scan iteration.  Peak grad memory is one layer's slice plus the small
 non-stack remainder (embed / norms / LM head / shared), never the model.
 
-Phases per step (n_micro == 1):
+Phases per step (n_micro == 1), each under the ``jax.named_scope`` of the
+same name (``repro.obs.scopes``):
 
   prelude   — ``jax.vjp`` over the non-stack prefix (embed, shared tree,
               encoder for encdec): produces the stream inputs + a vjp
               closure for later.
-  walk fwd  — gradient-free forward over the main stacks
+  walk.forward — gradient-free forward over the main stacks
               (``fused_stack_forward``), saving per-layer inputs only for
               non-reversible policy segments.
-  tail      — ``jax.vjp`` over final-norm + LM head + CE
+  head_loss — ``jax.vjp`` over final-norm + LM head + CE
               (``model.loss_from_streams``): loss, tail grads, and the
               output-stream cotangents that seed the walk.
-  probe     — (only when the optimizer clips) a backward walk whose
+  walk.probe — (only when the optimizer clips) a backward walk whose
               consumer reduces each layer's grads to a squared-norm
               scalar: global norm with deferred scale, the two-pass
               clipping strategy LOMO uses (arXiv:2306.09782).
-  update    — backward walk whose consumer applies
-              ``optimizer.update_leaf`` per layer; the stacked params and
+  walk.update — backward walk whose consumer applies
+              ``optimizer.update_leaf`` per layer (``opt.update``, as are
+              the group and final updates); the stacked params and
               optimizer state ride the scan CARRY and each layer's result
               lands in place (``write_layer``), so donation keeps the
               update in the parameters' own buffers — no old+new double
@@ -57,6 +59,7 @@ from repro.core.reversible import (accumulate_shared, fused_stack_backward,
                                    grouped_fused_stack_forward, read_layer,
                                    shared_cotangent, write_layer,
                                    zero_shared)
+from repro.obs import scopes
 from repro.optim.adamw import apply_subtree, clip_guard, global_norm_sq
 
 TAIL_KEYS = ("final_norm", "lm_head")
@@ -161,18 +164,25 @@ def make_fused_train_step(model, optimizer, *, n_micro: int = 1,
                                                       bx or None)
             return (x1, x2, shared), ctx
 
-        (x1, x2, shared), pre_vjp, ctx = jax.vjp(prelude, pre_p,
-                                                 has_aux=True)
+        with jax.named_scope(scopes.PRELUDE):
+            (x1, x2, shared), pre_vjp, ctx = jax.vjp(prelude, pre_p,
+                                                     has_aux=True)
         y1, y2 = x1, x2
         saves_all = []
-        for s, pol in zip(mains, policies):
-            if s.layout is not None:
-                runf = grouped_fused_stack_forward(s.fwd, s.layout, pol)
-            else:
-                runf = fused_stack_forward(s.fwd, pol)
-            (y1, y2), saves = runf(main_p[s.name], shared, ctx, y1, y2)
-            saves_all.append(saves)
+        with jax.named_scope(scopes.WALK_FORWARD):
+            for s, pol in zip(mains, policies):
+                if s.layout is not None:
+                    runf = grouped_fused_stack_forward(s.fwd, s.layout, pol)
+                else:
+                    runf = fused_stack_forward(s.fwd, pol)
+                (y1, y2), saves = runf(main_p[s.name], shared, ctx, y1, y2)
+                saves_all.append(saves)
         return (y1, y2), saves_all, shared, ctx, pre_vjp
+
+    def prelude_grads(pre_vjp, d1, d2, csh, shared):
+        with jax.named_scope(scopes.PRELUDE):
+            (dpre,) = pre_vjp((d1, d2, shared_cotangent(csh, shared)))
+        return dpre
 
     def backward(main_p, extras_by_stack, saves_all, shared, ctx,
                  y1, y2, ct1, ct2, consume_factory):
@@ -209,14 +219,19 @@ def make_fused_train_step(model, optimizer, *, n_micro: int = 1,
         """Forward + tail vjp for one microbatch."""
         (y1, y2), saves_all, shared, ctx, pre_vjp = forward(
             pre_p, main_p, mbatch)
-        loss, tvjp = jax.vjp(
-            lambda t, a, b: model.loss_from_streams(t, a, b, mbatch),
-            tail_p, y1, y2)
-        dtail, ct1, ct2 = tvjp(jnp.ones((), loss.dtype))
+        with jax.named_scope(scopes.HEAD_LOSS):
+            loss, tvjp = jax.vjp(
+                lambda t, a, b: model.loss_from_streams(t, a, b, mbatch),
+                tail_p, y1, y2)
+            dtail, ct1, ct2 = tvjp(jnp.ones((), loss.dtype))
         return (loss, saves_all, shared, ctx, pre_vjp, dtail,
                 (y1, y2), (ct1, ct2))
 
-    def step(params, opt_state, batch):
+    # the jitted module's name (``jit_fused_train_step``) is part of the
+    # persistent compile cache's key; op names are not, so a step that differs
+    # from another only in its scopes would load the other's executable and
+    # trace with its names
+    def fused_train_step(params, opt_state, batch):
         mask = mask_fn(params) if mask_fn else None
         pre_p, main_p, tail_p = split_like(params, main_names)
         if mask is not None:
@@ -253,7 +268,8 @@ def make_fused_train_step(model, optimizer, *, n_micro: int = 1,
                     mask=mk, skip=skip)
                 return (write_layer(pb_, new_sl, g),
                         write_layer(stb_, new_st, g))
-            return jax.lax.fori_loop(0, ng, gbody, (pb, stb))
+            with jax.named_scope(scopes.OPT_UPDATE):
+                return jax.lax.fori_loop(0, ng, gbody, (pb, stb))
 
         def finish_grouped(new_main, new_main_st, accs, scale, skip,
                            n_div=1):
@@ -278,20 +294,22 @@ def make_fused_train_step(model, optimizer, *, n_micro: int = 1,
                 mk = walk_view(main_mk.get(name), name)
 
                 def consume(i, lp, dlp, ex):
-                    new_lp, new_st = apply_subtree(
-                        optimizer, lp, dlp, ex, step=step_no, scale=scale,
-                        mask=mk, skip=skip)
-                    return new_lp, new_st, global_norm_sq(dlp)
+                    with jax.named_scope(scopes.OPT_UPDATE):
+                        new_lp, new_st = apply_subtree(
+                            optimizer, lp, dlp, ex, step=step_no,
+                            scale=scale, mask=mk, skip=skip)
+                        return new_lp, new_st, global_norm_sq(dlp)
                 return consume
             return for_stack
 
         def finish(new_main, new_main_st, dpre, dtail, scale, skip):
-            new_pre, new_pre_st = apply_subtree(
-                optimizer, pre_p, dpre, pre_st, step=step_no, scale=scale,
-                mask=pre_mk, skip=skip)
-            new_tail, new_tail_st = apply_subtree(
-                optimizer, tail_p, dtail, tail_st, step=step_no,
-                scale=scale, mask=tail_mk, skip=skip)
+            with jax.named_scope(scopes.OPT_UPDATE):
+                new_pre, new_pre_st = apply_subtree(
+                    optimizer, pre_p, dpre, pre_st, step=step_no,
+                    scale=scale, mask=pre_mk, skip=skip)
+                new_tail, new_tail_st = apply_subtree(
+                    optimizer, tail_p, dtail, tail_st, step=step_no,
+                    scale=scale, mask=tail_mk, skip=skip)
             new_params = merge_like(new_pre, new_main, new_tail)
             new_parts = {c: merge_like(
                 new_pre_st[c],
@@ -310,22 +328,26 @@ def make_fused_train_step(model, optimizer, *, n_micro: int = 1,
                 probe = lambda name: (          # noqa: E731
                     lambda i, lp, dlp, ex: (None, None,
                                             global_norm_sq(dlp)))
-                (_, _, sumsq, p_accs), (d1, d2), csh = backward(
-                    main_p, None, saves_all, shared, ctx, y1, y2, ct1, ct2,
-                    probe)
-                (dpre,) = pre_vjp((d1, d2, shared_cotangent(csh, shared)))
+                with jax.named_scope(scopes.WALK_PROBE):
+                    (_, _, sumsq, p_accs), (d1, d2), csh = backward(
+                        main_p, None, saves_all, shared, ctx, y1, y2, ct1,
+                        ct2, probe)
+                dpre = prelude_grads(pre_vjp, d1, d2, csh, shared)
                 total_sq = (global_norm_sq((dpre, dtail))
                             + sum(sumsq.values()) + base_norm_sq(p_accs))
                 scale, skip = clip_guard(total_sq, clip)
-                (new_main, new_main_st, _, accs), _, _ = backward(
-                    main_p, main_st_walk, saves_all, shared, ctx, y1, y2,
-                    ct1, ct2, upd_factory(scale, skip))
+                with jax.named_scope(scopes.WALK_UPDATE):
+                    (new_main, new_main_st, _, accs), _, _ = backward(
+                        main_p, main_st_walk, saves_all, shared, ctx, y1,
+                        y2, ct1, ct2, upd_factory(scale, skip))
             else:
                 scale, skip = 1.0, None
-                (new_main, new_main_st, sumsq, accs), (d1, d2), csh = \
-                    backward(main_p, main_st_walk, saves_all, shared, ctx,
-                             y1, y2, ct1, ct2, upd_factory(scale, skip))
-                (dpre,) = pre_vjp((d1, d2, shared_cotangent(csh, shared)))
+                with jax.named_scope(scopes.WALK_UPDATE):
+                    (new_main, new_main_st, sumsq, accs), (d1, d2), csh = \
+                        backward(main_p, main_st_walk, saves_all, shared,
+                                 ctx, y1, y2, ct1, ct2,
+                                 upd_factory(scale, skip))
+                dpre = prelude_grads(pre_vjp, d1, d2, csh, shared)
                 total_sq = (global_norm_sq((dpre, dtail))
                             + sum(sumsq.values()) + base_norm_sq(accs))
             new_main, new_main_st = finish_grouped(new_main, new_main_st,
@@ -356,10 +378,11 @@ def make_fused_train_step(model, optimizer, *, n_micro: int = 1,
                 (loss, saves_all, shared, ctx, pre_vjp, dtail,
                  (y1, y2), (ct1, ct2)) = run_micro(pre_p, main_p, tail_p,
                                                    mbatch)
-                (_, acc_main, _, accs), (d1, d2), csh = backward(
-                    main_p, acc_main, saves_all, shared, ctx, y1, y2,
-                    ct1, ct2, acc_factory)
-                (dpre,) = pre_vjp((d1, d2, shared_cotangent(csh, shared)))
+                with jax.named_scope(scopes.WALK_UPDATE):
+                    (_, acc_main, _, accs), (d1, d2), csh = backward(
+                        main_p, acc_main, saves_all, shared, ctx, y1, y2,
+                        ct1, ct2, acc_factory)
+                dpre = prelude_grads(pre_vjp, d1, d2, csh, shared)
                 add = lambda a, g: a + g.astype(a.dtype)    # noqa: E731
                 acc_base = {n: jax.tree_util.tree_map(add, acc_base[n],
                                                       accs[n])
@@ -396,14 +419,16 @@ def make_fused_train_step(model, optimizer, *, n_micro: int = 1,
                     pb, stb = carry
                     g = jax.tree_util.tree_map(lambda a: a / n_micro,
                                                read_layer(acc_n, j))
-                    new_lp, new_st = apply_subtree(
-                        optimizer, read_layer(pb, j), g,
-                        read_layer(stb, j), step=step_no, scale=scale,
-                        mask=mk, skip=skip)
+                    with jax.named_scope(scopes.OPT_UPDATE):
+                        new_lp, new_st = apply_subtree(
+                            optimizer, read_layer(pb, j), g,
+                            read_layer(stb, j), step=step_no, scale=scale,
+                            mask=mk, skip=skip)
                     return (write_layer(pb, new_lp, j),
                             write_layer(stb, new_st, j))
-                new_main[n], new_main_st[n] = jax.lax.fori_loop(
-                    0, nl, ubody, (pw, stw))
+                with jax.named_scope(scopes.WALK_UPDATE):
+                    new_main[n], new_main_st[n] = jax.lax.fori_loop(
+                        0, nl, ubody, (pw, stw))
                 if lay is not None:
                     new_main[n] = dict(new_main[n],
                                        base=main_p[n]["base"])
@@ -419,4 +444,4 @@ def make_fused_train_step(model, optimizer, *, n_micro: int = 1,
                    "step": new_opt["step"]}
         return new_params, new_opt, metrics
 
-    return step
+    return fused_train_step

@@ -13,6 +13,7 @@ from typing import Callable, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scopes
 from repro.optim.adamw import global_norm
 
 
@@ -118,7 +119,9 @@ def make_train_step(model, optimizer, *, n_micro: int = 1,
 
         mask = mask_fn(params) if mask_fn else None
         gnorm = global_norm(grads)
-        params, opt_state = optimizer.update(grads, opt_state, params, mask=mask)
+        with jax.named_scope(scopes.OPT_UPDATE):
+            params, opt_state = optimizer.update(grads, opt_state, params,
+                                                 mask=mask)
         metrics = {"loss": loss, "grad_norm": gnorm,
                    "grads_finite": jnp.isfinite(gnorm),
                    "step": opt_state["step"]}

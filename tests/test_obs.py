@@ -694,20 +694,3 @@ def test_telemetry_overhead_bounded(audit_run):
         f"telemetry emit path took {overhead['s']:.3f}s of {wall:.1f}s "
         f"({100 * overhead['s'] / wall:.2f}% > 5%)")
 
-
-# ------------------------------------------------------- estimator hook
-
-def test_train_step_flops_policy_multipliers():
-    from repro.configs.base import get_config
-    from repro.memory.estimator import train_step_flops
-    from repro.models.model import Model
-
-    cfg = get_config("qwen2-moe-a2.7b", reduced=True)
-    model = Model(cfg)
-    rev = train_step_flops(model, 2, 64, save_memory=True)
-    store = train_step_flops(model, 2, 64, save_memory=False)
-    assert rev / store == pytest.approx(5.0 / 3.0)   # reversible vs store
-    mixed = train_step_flops(model, 2, 64,
-                             save_memory=["store", "reversible"])
-    assert store < mixed < rev
-    assert train_step_flops(model, 4, 64, save_memory=True) == 2 * rev

@@ -118,3 +118,42 @@ def test_paged_decode_compiles(compile_text):
         ((P, page, KV, hd), BF16), ((P, page), I32), ((B, n_pages), I32),
         ((B,), I32))
     assert "tpu_custom_call" in text
+
+
+# one small call per named kernel, (8, 128)-tiled; mamba_ssd and rwkv6_scan
+# do not lower for the TPU yet (their bodies use cumsum) and are checked in
+# interpret mode by tests/test_scopes.py
+NAMED = {
+    "flash_fwd": (lambda q, k, v: fa.flash_attention(
+        q, k, v, causal=True, scale=0.1, interpret=False, return_lse=True),
+        [((1, 2, 256, 128), BF16)] * 3),
+    "flash_dq": (lambda q, k, v, lse, delta, do: fa.flash_attention_bwd(
+        q, k, v, lse, delta, do, causal=True, scale=0.1, interpret=False),
+        [((1, 2, 256, 128), BF16)] * 3 + [((1, 2, 256), F32)] * 2
+        + [((1, 2, 256, 128), BF16)]),
+    "gmm": (lambda a, b, te: grouped_matmul_pallas(a, b, te, block_m=128,
+                                                   interpret=False),
+            [((256, 128), BF16), ((2, 128, 256), BF16), ((2,), I32)]),
+    "paged_decode": (lambda q, k, v, pos, pt, t: pk.paged_attention_pallas(
+        q, k, v, pos, pt, t, kv_len=64, window=None, interpret=False),
+        [((2, 4, 128), BF16), ((8, 16, 2, 128), BF16),
+         ((8, 16, 2, 128), BF16), ((8, 16), I32), ((2, 4), I32),
+         ((2,), I32)]),
+    "rmsnorm": (lambda x, s: rmsnorm_kernel(x, s, interpret=False),
+                [((256, 128), BF16), ((128,), BF16)]),
+}
+NAMED["flash_dkv"] = NAMED["flash_dq"]     # the backward runs both kernels
+
+
+def rmsnorm_kernel(x, s, *, interpret):
+    from repro.kernels.rmsnorm import rmsnorm
+    return rmsnorm(x, s, interpret=interpret)
+
+
+@pytest.mark.parametrize("name", sorted(NAMED))
+def test_kernel_custom_call_carries_its_name(compile_text, name):
+    fn, shapes = NAMED[name]
+    calls = [line for line in compile_text(fn, *shapes).splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert any(line.lstrip().startswith((f"%{name}.", f"ROOT %{name}."))
+               and f'{name}/pallas_call"' in line for line in calls), calls
