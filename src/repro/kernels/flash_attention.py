@@ -6,7 +6,13 @@ TPU), so the running max / denominator / accumulator live in f32 VMEM scratch
 and persist across kv steps of one q block.  MXU work: q @ k^T and p @ v per
 (BQ, BK) tile; the ops wrapper pads head_dim to a multiple of 128.  With
 ``return_lse`` the kernel also emits the per-row logsumexp (m + log l), the
-O(S) residual the backward kernels recompute probability tiles from.
+O(S) residual the backward kernels recompute probability tiles from.  Tiles
+fully masked by causal/window (dead) are skipped with ``pl.when``, and the
+k/v index maps clamp a dead step to the q block's live kv range, so it keeps
+the block the previous step holds and the pipeline issues no copy for it.
+Causal calls on square tiles with an even number of q blocks fold the grid
+to (B * H, Sq/BQ/2, Sq/BQ + 1), pairing q block a with q block Sq/BQ-1-a, so
+the grid holds no dead step at all (``_fwd_walk``).
 
 Backward (DESIGN.md §8) splits into two passes over the same recomputed
 p tiles — p = exp(s - lse) needs no second online softmax:
@@ -20,7 +26,7 @@ p tiles — p = exp(s - lse) needs no second online softmax:
 
 Both passes take the precomputed delta = rowsum(dO * O) (the softmax-jacobian
 row term), apply softcap's tanh chain rule where enabled, and skip dead tiles
-(fully masked by causal/window) via ``pl.when`` on the grid indices.
+via ``pl.when`` on the grid indices, as the forward does.
 
 ``flash_attention_fwd_jax`` / ``flash_attention_bwd_jax`` are the pure-JAX
 tiled fallbacks (the off-TPU production path, same pattern as the grouped-GEMM
@@ -61,41 +67,94 @@ def _tile_mask(iq, jk, *, causal: bool, window: Optional[int],
     return _mask_positions(pos_q, pos_k, causal, window)
 
 
+def _tile_live(iq, jk, *, causal: bool, window: Optional[int],
+               bq: int, bk: int):
+    """False iff tile (iq, jk) is fully masked (dead) under causal/window."""
+    live = jnp.bool_(True)
+    if causal:                          # max q pos >= min k pos
+        live &= iq * bq + (bq - 1) >= jk * bk
+    if window is not None:              # min (q - k) < window
+        live &= iq * bq - (jk * bk + bk - 1) < window
+    return live
+
+
+def _live_kv_block(i, j, *, causal: bool, window: Optional[int],
+                   bq: int, bk: int, nk: int):
+    """kv block index for grid step (i, j) of the unfolded forward: j clamped
+    to the live range of q block i, so a dead step names the block the
+    previous step already holds and the pipeline issues no copy for it."""
+    if window is not None:              # first live: holds i*bq - window + 1
+        lo = jnp.maximum(i * bq - window + 1, 0) // bk
+        j = jnp.maximum(j, jnp.minimum(lo, nk - 1))
+    if causal:                          # last live: holds i*bq + bq - 1
+        j = jnp.minimum(j, (i * bq + bq - 1) // bk)
+    return j
+
+
+def _fwd_walk(*, causal: bool, window: Optional[int], bq: int, bk: int,
+              nq: int, nk: int):
+    """The forward's grid over (q block, kv block) and ``step(a, b)`` ->
+    (q block, kv tile, kv block to fetch, first step of the row, last step
+    of the row) for grid step (a, b).
+
+    Causal attention on square tiles with an even number of q blocks is
+    walked folded: grid (nq/2, nq+1) visits row a for b <= a and then row
+    nq-1-a, so each pair of rows holds exactly its nq+1 live tiles and no
+    step is dead (a dead step still costs its grid-step overhead).  Every
+    other call walks (nq, nk), skips dead tiles and clamps their kv block.
+    Both walk each row's kv tiles in ascending order, so o and lse agree to
+    the bit."""
+    if causal and window is None and bq == bk and nq == nk and nq % 2 == 0:
+        def step(a, b):
+            lower = b <= a
+            jk = jnp.where(lower, b, b - a - 1)
+            return (jnp.where(lower, a, nq - 1 - a), jk, jk,
+                    (b == 0) | (b == a + 1), (b == a) | (b == nq))
+        return (nq // 2, nq + 1), step
+
+    def step(a, b):
+        kv = _live_kv_block(a, b, causal=causal, window=window, bq=bq, bk=bk,
+                            nk=nk)
+        return a, b, kv, b == 0, b == nk - 1
+    return (nq, nk), step
+
+
 def _kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_scr, l_scr, acc_scr, *,
             scale: float, causal: bool, window: Optional[int],
-            softcap: Optional[float], bq: int, bk: int, nk: int):
-    iq = pl.program_id(1)
-    jk = pl.program_id(2)
+            softcap: Optional[float], bq: int, bk: int, step):
+    iq, jk, _, first, last = step(pl.program_id(1), pl.program_id(2))
 
-    @pl.when(jk == 0)
+    @pl.when(first)
     def _init():
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    q = q_ref[0].astype(jnp.float32)                    # (bq, hd)
-    k = k_ref[0].astype(jnp.float32)                    # (bk, hd)
-    v = v_ref[0].astype(jnp.float32)
+    @pl.when(_tile_live(iq, jk, causal=causal, window=window, bq=bq, bk=bk))
+    def _compute():
+        q = q_ref[0].astype(jnp.float32)                # (bq, hd)
+        k = k_ref[0].astype(jnp.float32)                # (bk, hd)
+        v = v_ref[0].astype(jnp.float32)
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-    if softcap is not None:
-        s = jnp.tanh(s / softcap) * softcap
+        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if softcap is not None:
+            s = jnp.tanh(s / softcap) * softcap
 
-    mask = _tile_mask(iq, jk, causal=causal, window=window, bq=bq, bk=bk)
-    s = jnp.where(mask, s, NEG_INF)
+        mask = _tile_mask(iq, jk, causal=causal, window=window, bq=bq, bk=bk)
+        s = jnp.where(mask, s, NEG_INF)
 
-    m_prev = m_scr[...]                                  # (bq, 1)
-    m_cur = jnp.max(s, axis=1, keepdims=True)
-    m_new = jnp.maximum(m_prev, m_cur)
-    p = jnp.exp(s - m_new)                               # (bq, bk)
-    alpha = jnp.exp(m_prev - m_new)
-    l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
-    acc_scr[...] = alpha * acc_scr[...] + jax.lax.dot(
-        p, v, preferred_element_type=jnp.float32)
-    m_scr[...] = m_new
+        m_prev = m_scr[...]                              # (bq, 1)
+        m_cur = jnp.max(s, axis=1, keepdims=True)
+        m_new = jnp.maximum(m_prev, m_cur)
+        p = jnp.exp(s - m_new)                           # (bq, bk)
+        alpha = jnp.exp(m_prev - m_new)
+        l_scr[...] = alpha * l_scr[...] + jnp.sum(p, axis=1, keepdims=True)
+        acc_scr[...] = alpha * acc_scr[...] + jax.lax.dot(
+            p, v, preferred_element_type=jnp.float32)
+        m_scr[...] = m_new
 
-    @pl.when(jk == nk - 1)
+    @pl.when(last)
     def _finish():
         l = jnp.maximum(l_scr[...], 1e-30)
         o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
@@ -128,21 +187,30 @@ def flash_attention(q, k, v, *, causal: bool = True,
     kr = k.reshape(B * KV, Skv, hd)
     vr = v.reshape(B * KV, Skv, hd)
 
+    grid, step = _fwd_walk(causal=causal, window=window, bq=bq, bk=bk,
+                           nq=nq, nk=nk)
     kern = functools.partial(
         _kernel, scale=scale if scale is not None else hd ** -0.5,
-        causal=causal, window=window, softcap=softcap, bq=bq, bk=bk, nk=nk)
+        causal=causal, window=window, softcap=softcap, bq=bq, bk=bk,
+        step=step)
+
+    def q_rows(bh, a, b):
+        return bh, step(a, b)[0], 0
+
+    def kv_rows(bh, a, b):
+        return bh // G, step(a, b)[2], 0
 
     out, lse = pl.pallas_call(
         kern,
-        grid=(B * H, nq, nk),
+        grid=(B * H, *grid),
         in_specs=[
-            pl.BlockSpec((1, bq, hd), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bk, hd), lambda bh, i, j: (bh // G, j, 0)),
-            pl.BlockSpec((1, bk, hd), lambda bh, i, j: (bh // G, j, 0)),
+            pl.BlockSpec((1, bq, hd), q_rows),
+            pl.BlockSpec((1, bk, hd), kv_rows),
+            pl.BlockSpec((1, bk, hd), kv_rows),
         ],
         out_specs=[
-            pl.BlockSpec((1, bq, hd), lambda bh, i, j: (bh, i, 0)),
-            pl.BlockSpec((1, bq, 1), lambda bh, i, j: (bh, i, 0)),
+            pl.BlockSpec((1, bq, hd), q_rows),
+            pl.BlockSpec((1, bq, 1), q_rows),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B * H, Sq, hd), q.dtype),
@@ -163,17 +231,6 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
 
 # ================================================================== backward
-
-def _tile_live(iq, jk, *, causal: bool, window: Optional[int],
-               bq: int, bk: int):
-    """False iff tile (iq, jk) is fully masked (dead) under causal/window."""
-    live = jnp.bool_(True)
-    if causal:                          # max q pos >= min k pos
-        live &= iq * bq + (bq - 1) >= jk * bk
-    if window is not None:              # min (q - k) < window
-        live &= iq * bq - (jk * bk + bk - 1) < window
-    return live
-
 
 def _p_ds_tiles(q, k, v, do, lse, delta, iq, jk, *, scale, causal, window,
                 softcap, bq, bk):

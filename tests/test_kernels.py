@@ -4,6 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
 
 
@@ -65,6 +66,95 @@ def test_flash_attention_block_shapes(block_q, block_k):
     want = ref.flash_attention_ref(q, k, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                rtol=2e-5, atol=2e-5)
+
+
+def _dense_lse(q, k, *, causal, window, softcap):
+    """Per-row logsumexp of the masked scores, (B, H, Sq), as the dense
+    reference computes them."""
+    B, H, Sq, hd = q.shape
+    KV, Skv = k.shape[1], k.shape[2]
+    qg = q.reshape(B, KV, H // KV, Sq, hd)
+    s = jnp.einsum("bkgqh,bksh->bkgqs", qg, k) * hd ** -0.5
+    if softcap is not None:
+        s = jnp.tanh(s / softcap) * softcap
+    pq, pk = jnp.arange(Sq)[:, None], jnp.arange(Skv)[None, :]
+    mask = jnp.ones((Sq, Skv), bool)
+    if causal:
+        mask &= pq >= pk
+    if window is not None:
+        mask &= (pq - pk) < window
+    s = jnp.where(mask, s, -jnp.inf)
+    return jax.nn.logsumexp(s, axis=-1).reshape(B, H, Sq)
+
+
+@pytest.mark.parametrize("S,H,KV,block_q,block_k,window,softcap", [
+    (512, 2, 2, 128, 128, None, None),    # causal, folded: 6 of 16 dead
+    (384, 2, 2, 128, 128, None, None),    # odd q-block count: unfolded
+    (512, 2, 2, 128, 256, None, None),    # bq != bk
+    (512, 2, 2, 256, 128, None, None),
+    (512, 2, 2, 128, 128, 130, None),     # window < S: leading tiles dead
+    (512, 4, 2, 128, 128, None, 30.0),    # GQA with softcap
+])
+def test_flash_forward_skips_dead_tiles(S, H, KV, block_q, block_k, window,
+                                        softcap):
+    """The Pallas forward skips fully masked tiles (and fetches no kv block
+    for them); its o and lse still match the tiled fallback and the dense
+    reference."""
+    hd = 64
+    ks = jax.random.split(jax.random.PRNGKey(4), 3)
+    q = jax.random.normal(ks[0], (1, H, S, hd))
+    k = jax.random.normal(ks[1], (1, KV, S, hd))
+    v = jax.random.normal(ks[2], (1, KV, S, hd))
+    kw = dict(causal=True, window=window, softcap=softcap)
+    o, lse = fa.flash_attention(q, k, v, block_q=block_q, block_k=block_k,
+                                interpret=True, return_lse=True, **kw)
+    o_jax, lse_jax = fa.flash_attention_fwd_jax(q, k, v, block_q=block_q,
+                                                **kw)
+    want_o = ref.flash_attention_ref(q, k, v, **kw)
+    want_lse = _dense_lse(q, k, **kw)
+    for got, want in ((o, o_jax), (lse, lse_jax), (o, want_o),
+                      (lse, want_lse)):
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("causal,window,bq,bk,S", [
+    (True, None, 128, 128, 8192),     # folded: no dead step
+    (True, None, 128, 128, 384),      # odd q-block count: unfolded
+    (True, None, 64, 128, 1024),
+    (True, 130, 128, 128, 512),
+    (True, 4096, 128, 128, 8192),
+    (False, 200, 64, 64, 512),
+])
+def test_flash_forward_walk_visits_each_live_tile_once(causal, window, bq,
+                                                        bk, S):
+    """The forward's grid walk computes every live tile once, each q block's
+    kv tiles in ascending order between its first and last step; a dead
+    step fetches the block its predecessor holds (the row's first live
+    block at the row's first step), so the pipeline copies nothing for it."""
+    nq, nk = S // bq, S // bk
+    tile = dict(causal=causal, window=window, bq=bq, bk=bk)
+    grid, step = fa._fwd_walk(nq=nq, nk=nk, **tile)
+    folded = causal and window is None and bq == bk and nq % 2 == 0
+    assert grid == ((nq // 2, nq + 1) if folded else (nq, nk))
+    rows = {}
+    for a in range(grid[0]):
+        for b in range(grid[1]):
+            iq, jk, kv, first, last = (int(x) for x in step(a, b))
+            if first:
+                assert iq not in rows
+                rows[iq] = []
+                live = [j for j in range(nk) if fa._tile_live(iq, j, **tile)]
+                prev = live[0]
+            if fa._tile_live(iq, jk, **tile):
+                assert kv == jk
+                rows[iq].append(jk)
+            else:
+                assert not folded and kv == prev
+            prev = kv
+            if last:
+                assert rows[iq] == live
+    assert sorted(rows) == list(range(nq))
 
 
 @pytest.mark.parametrize("shape", [(4, 128), (2, 7, 300), (1, 128, 2048)])

@@ -62,9 +62,11 @@ def compile_text(one_chip):
 BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
 
 # (B, H, KV, S, hd after padding to 128, window): qwen2-moe-a2.7b at seq
-# 2048 (MHA, hd 128); h2o-danube-1.8b at seq 4096 (GQA 4, hd 80 -> 128,
-# sliding window 4096)
+# 2048 (MHA, hd 128) and at the fine-tune cell's 1 x 8192 (64 x 64 tiles per
+# head, dead causal steps clamped to the last live kv block); h2o-danube-1.8b
+# at seq 4096 (GQA 4, hd 80 -> 128, sliding window 4096)
 FLASH = {"qwen2-moe": (4, 16, 16, 2048, 128, None),
+         "qwen2-moe-8k": (1, 16, 16, 8192, 128, None),
          "h2o-danube": (2, 32, 8, 4096, 128, 4096)}
 
 
