@@ -75,7 +75,9 @@ def _path(p) -> tuple:
 
 def program_counts(model, ref) -> dict:
     """Stack counts of every leaf as the program holds it, after checking
-    that the program's tree is the reference's layout."""
+    that the program's tree is the reference's layout: each stack as deep
+    as the reference's, a router at least as wide as the published experts
+    and at least the experts held (more is padding)."""
     flat = {_path(p): a for p, a in
             jax.tree_util.tree_flatten_with_path(model.abstract_params())[0]}
     dtype = jnp.dtype(model.cfg.dtype)
@@ -91,9 +93,9 @@ def program_counts(model, ref) -> dict:
             cnt, core = a.shape[:ns], a.shape[ns:]
         ok = tuple(core) == tuple(leaf.core) and a.dtype == dtype
         if ns:
-            ok &= cnt[0] == ref.L
-        if "experts" in leaf.stack:
-            ok &= cnt[1] >= ref.E
+            ok &= cnt[0] == ref.depth(leaf)
+        if ns > 1:
+            ok &= cnt[1] >= ref.count(leaf.stack[1])
         if not ok:
             raise ValueError(f"leaf {leaf.path}: program {a.shape} "
                              f"{a.dtype}, reference core {leaf.core}")
@@ -112,7 +114,8 @@ def _get(tree, path):
 def _names(ref, leaf, n):
     if not leaf.stack:
         return [leaf.path[0]]
-    return [f"layers.{i}.{ref.lname(leaf)}" for i in range(n)]
+    stack, lname = leaf.path[1], ref.lname(leaf)
+    return [ref.reading(stack, i, lname) for i in range(n)]
 
 
 _READ_FNS = {}

@@ -8,8 +8,7 @@ from chipbench.peaks import peaks_for
 
 
 def served_flops(cfg, requests) -> float:
-    mm = counts.layer_matmul_flops(cfg) * cfg["num_hidden_layers"] \
-        + 2.0 * cfg["hidden_size"] * cfg["vocab_size"]
+    mm = counts.matmul_flops_per_token(cfg)
     att = counts.attention_flops_per_token(cfg, 1.0) * cfg["num_hidden_layers"]
     w = counts.window_of(cfg)
     total = 0.0

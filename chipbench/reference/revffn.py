@@ -9,12 +9,15 @@ the hidden state splits into two streams of width d/2, and each layer is
 
 with RMS norms that scale by (1 + w), rotary positions (rotate-half), grouped
 query attention (causal, and windowed where the configuration says so), and
-an FFN that is SwiGLU or a top-k mixture of SwiGLU experts with sigmoid-gated
-shared experts.  The output is rms_norm(concat(Y1, Y2)) @ lm_head, and the
-loss is the masked next-token cross-entropy.  The gradients come from the
-plain chain rule layer by layer (no inversion of the coupling), in float32
-with ``Precision.HIGHEST``.  Departures from the source model that the
-program makes are stated in the configuration files (``reduced``).
+an FFN that is SwiGLU (a ``dense`` layer) or a top-k mixture of SwiGLU
+experts with shared experts (a ``moe`` layer).  The layers come as stacks in
+order (``chipbench.shape``: leading dense layers, then the rest), and a MoE
+layer routes over every published expert but adds only the part of the
+experts this chip holds.  The output is rms_norm(concat(Y1, Y2)) @ lm_head,
+and the loss is the masked next-token cross-entropy.  The gradients come
+from the plain chain rule layer by layer (no inversion of the coupling), in
+float32 with ``Precision.HIGHEST``.  Departures from the source model that
+the program makes are stated in the configuration files (``reduced``).
 
 It imports nothing of the program and takes nothing the program made: the
 weights are drawn again from the seed (``chipbench.weights``).
@@ -34,6 +37,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from chipbench import shape
 from chipbench import weights as W
 from chipbench.weights import Leaf
 
@@ -69,27 +73,35 @@ _q8.defvjp(_q8_fwd, _q8_bwd)
 # ------------------------------------------------------------------ model
 
 class RevFFN:
-    """Sizes from a configuration file (Hugging Face key names)."""
+    """Sizes from a configuration file (Hugging Face key names), and the
+    model as an ordered list of stacks (``chipbench.shape``): each stack has
+    its own depth and kind, and the layer of each kind is its own function.
+
+    A reference for another architecture subclasses this one and overrides
+    ``attention`` (with ``attn_leaves``), ``route`` or ``shared``; the walk,
+    the weights and the readings stay as they are."""
 
     def __init__(self, cfg: dict, mode: str = "f32"):
         assert mode in ("f32", "fp8"), mode
+        self.cfg = cfg
         self.mode = mode
         self.d = cfg["hidden_size"]
         self.half = self.d // 2
         self.H = cfg["num_attention_heads"]
         self.KV = cfg["num_key_value_heads"]
         self.hd = cfg.get("head_dim") or self.d // self.H
-        self.L = cfg["num_hidden_layers"]
         self.V = cfg["vocab_size"]
         self.eps = cfg["rms_norm_eps"]
         self.theta = float(cfg["rope_theta"])
         self.window = (cfg.get("sliding_window")
                        if cfg.get("use_sliding_window", True) else None)
         self.qkv_bias = bool(cfg.get("qkv_bias", False))
-        self.E = int(cfg.get("num_experts", 0) or 0)
+        self.stacks = shape.stacks(cfg)
+        self.held, self.routed = shape.routed_experts(cfg)
         self.top_k = int(cfg.get("num_experts_per_tok", 0) or 0)
         self.fe = cfg.get("moe_intermediate_size")
-        self.fs = cfg.get("shared_expert_intermediate_size")
+        self.fs = shape.shared_width(cfg)
+        self.shared_gate = shape.shared_gated(cfg)
         self.ff = cfg["intermediate_size"]
         self.norm_topk = bool(cfg.get("norm_topk_prob", False))
         self.q_chunk = 512
@@ -98,56 +110,90 @@ class RevFFN:
     # -------------------------------------------------------------- layout
 
     def layout(self) -> List[Leaf]:
-        d, half, H, KV, hd = self.d, self.half, self.H, self.KV, self.hd
-        lay, ex = ("layers",), ("layers", "experts")
-        S = ("stacks", "layers")
-        out = [Leaf(("embed",), (), (self.V, d), "embed"),
-               Leaf(("final_norm",), (), (d,), "norm"),
-               Leaf(("lm_head",), (), (d, self.V), "fan_in")]
-        for n in ("norm1", "norm2", "norm_mlp"):
-            out.append(Leaf(S + (n,), lay, (half,), "norm"))
+        out = [Leaf(("embed",), (), (self.V, self.d), "embed"),
+               Leaf(("final_norm",), (), (self.d,), "norm"),
+               Leaf(("lm_head",), (), (self.d, self.V), "fan_in")]
+        for name, _, kind in self.stacks:
+            out += self.stack_leaves(name, kind)
+        return out
+
+    def stack_leaves(self, name: str, kind: str) -> List[Leaf]:
+        """One stack's leaves, each with a leading axis over its layers."""
+        d, half = self.d, self.half
+        S, lay = ("stacks", name), ("layers",)
+        out = [Leaf(S + (n,), lay, (half,), "norm")
+               for n in ("norm1", "norm2", "norm_mlp")]
         for a in ("attn_ad", "mlp_ad"):
             out.append(Leaf(S + (a, "p_up"), lay, (half, d), "fan_in"))
             out.append(Leaf(S + (a, "p_down"), lay, (d, half), "small"))
-        A = S + ("attn",)
-        out += [Leaf(A + ("wq",), lay, (d, H * hd), "fan_in"),
-                Leaf(A + ("wk",), lay, (d, KV * hd), "fan_in"),
-                Leaf(A + ("wv",), lay, (d, KV * hd), "fan_in"),
-                Leaf(A + ("wo",), lay, (H * hd, d), "fan_in")]
+        return out + self.attn_leaves(S, kind) + self.ffn_leaves(S, kind)
+
+    def attn_leaves(self, S, kind: str) -> List[Leaf]:
+        if shape.latent(self.cfg):
+            raise NotImplementedError(
+                "latent attention needs a reference that overrides "
+                "attn_leaves and attention")
+        d, H, KV, hd = self.d, self.H, self.KV, self.hd
+        A, lay = S + ("attn",), ("layers",)
+        out = [Leaf(A + ("wq",), lay, (d, H * hd), "fan_in"),
+               Leaf(A + ("wk",), lay, (d, KV * hd), "fan_in"),
+               Leaf(A + ("wv",), lay, (d, KV * hd), "fan_in"),
+               Leaf(A + ("wo",), lay, (H * hd, d), "fan_in")]
         if self.qkv_bias:
             out += [Leaf(A + ("bq",), lay, (H * hd,), "bias"),
                     Leaf(A + ("bk",), lay, (KV * hd,), "bias"),
                     Leaf(A + ("bv",), lay, (KV * hd,), "bias")]
-        if self.E:
-            M = S + ("moe",)
-            fe, fs = self.fe, self.fs
-            out += [Leaf(M + ("router",), ex, (d,), "router",
-                         experts_last=True),
-                    Leaf(M + ("w_gate",), ex, (d, fe), "fan_in"),
-                    Leaf(M + ("w_up",), ex, (d, fe), "fan_in"),
-                    Leaf(M + ("w_down",), ex, (fe, d), "fan_in")]
-            if fs:
-                out += [Leaf(M + ("shared", "w_gate"), lay, (d, fs), "fan_in"),
-                        Leaf(M + ("shared", "w_up"), lay, (d, fs), "fan_in"),
-                        Leaf(M + ("shared", "w_down"), lay, (fs, d), "fan_in"),
-                        Leaf(M + ("shared", "gate"), lay, (d, 1), "fan_in")]
-        else:
-            M = S + ("mlp",)
-            out += [Leaf(M + ("w_gate",), lay, (d, self.ff), "fan_in"),
-                    Leaf(M + ("w_up",), lay, (d, self.ff), "fan_in"),
-                    Leaf(M + ("w_down",), lay, (self.ff, d), "fan_in")]
+        return out
+
+    def ffn_leaves(self, S, kind: str) -> List[Leaf]:
+        """A ``moe`` layer's router has an axis over every published expert
+        (``routed``), its experts one over the experts held (``experts``)."""
+        d, lay = self.d, ("layers",)
+        if kind == "dense":
+            M, ff = S + ("mlp",), self.ff
+            return [Leaf(M + ("w_gate",), lay, (d, ff), "fan_in"),
+                    Leaf(M + ("w_up",), lay, (d, ff), "fan_in"),
+                    Leaf(M + ("w_down",), lay, (ff, d), "fan_in")]
+        M, fe, fs = S + ("moe",), self.fe, self.fs
+        ex = ("layers", "experts")
+        out = [Leaf(M + ("router",), ("layers", "routed"), (d,), "router",
+                    experts_last=True),
+               Leaf(M + ("w_gate",), ex, (d, fe), "fan_in"),
+               Leaf(M + ("w_up",), ex, (d, fe), "fan_in"),
+               Leaf(M + ("w_down",), ex, (fe, d), "fan_in")]
+        if fs:
+            out += [Leaf(M + ("shared", "w_gate"), lay, (d, fs), "fan_in"),
+                    Leaf(M + ("shared", "w_up"), lay, (d, fs), "fan_in"),
+                    Leaf(M + ("shared", "w_down"), lay, (fs, d), "fan_in")]
+            if self.shared_gate:
+                out.append(Leaf(M + ("shared", "gate"), lay, (d, 1),
+                                "fan_in"))
         return out
 
     def top_leaves(self):
         return [l for l in self.layout() if not l.stack]
 
-    def layer_leaves(self):
-        return [l for l in self.layout() if l.stack]
+    def leaves_of(self, stack: str):
+        return [l for l in self.layout() if l.stack and l.path[1] == stack]
+
+    def depth(self, leaf: Leaf) -> int:
+        """Layers in the leaf's stack."""
+        return next(n for name, n, _ in self.stacks if name == leaf.path[1])
+
+    def count(self, axis: str) -> int:
+        """Entries along a stack axis after the layers: every published
+        expert (``routed``) or the experts held (``experts``)."""
+        return {"routed": self.routed, "experts": self.held}[axis]
 
     @staticmethod
     def lname(leaf: Leaf) -> str:
         """Name of a leaf inside one layer ('attn/wq', 'moe/shared/gate')."""
         return "/".join(leaf.path[2:])
+
+    @staticmethod
+    def reading(stack: str, i: int, lname: str) -> str:
+        """Name of one layer's leaf among the readings ('layers.0.attn/wq')."""
+        return f"{stack}.{i}.{lname}"
 
     # -------------------------------------------------------------- weights
 
@@ -159,11 +205,10 @@ class RevFFN:
 
     def draw_layer(self, seed: int, leaf: Leaf, layer: int):
         """One layer of a stacked leaf, with the reference's experts, in
-        the layout the reference computes with (router as (d, E))."""
-        ck = (leaf, self.E)
+        the layout the reference computes with (router as (d, routed))."""
+        counts = tuple(self.count(a) for a in leaf.stack[1:])
+        ck = (leaf, counts)
         if ck not in _DRAW_CACHE:
-            counts = (self.E,) if "experts" in leaf.stack else ()
-
             def f(k, layer):
                 x = W.draw_stacked(W.path_key(k, leaf.path), leaf, counts,
                                    BF16, index=(layer,))
@@ -192,7 +237,7 @@ class RevFFN:
         a, b = x[..., :half], x[..., half:]
         return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], -1)
 
-    def attention(self, lp, x1, x2):
+    def attention(self, kind, lp, x1, x2):
         B, S, _ = x1.shape
         H, KV, hd = self.H, self.KV, self.hd
         G = H // KV
@@ -238,37 +283,63 @@ class RevFFN:
             "td,df->tf", x, wu)
         return self.mm("tf,fd->td", h, wd)
 
-    def ffn(self, lp, y1):
+    def route(self, lp, x):
+        """(gate values, expert ids), each (T, top_k), over every published
+        expert: softmax of the router's logits, top-k, renormalised over
+        the k chosen where the configuration says so."""
+        if self.cfg.get("scoring_func", "softmax") != "softmax":
+            raise NotImplementedError(
+                f"scoring_func {self.cfg['scoring_func']!r} needs a "
+                "reference that overrides route")
+        logits = self.mm("td,de->te", x, lp["moe/router"])
+        probs = jax.nn.softmax(logits, axis=-1)
+        gv, gi = jax.lax.top_k(probs, self.top_k)
+        if self.norm_topk:
+            gv = gv / (jnp.sum(gv, -1, keepdims=True) + 1e-9)
+        return gv, gi
+
+    def experts(self, lp, x, gv, gi):
+        """The experts held, weighted by their gates: an id outside
+        0 .. held-1 is an expert held elsewhere and adds nothing here."""
+        held = lp["moe/w_gate"].shape[0]
+        comb = jnp.sum(jax.nn.one_hot(gi, held, dtype=F32)
+                       * gv[..., None], axis=1)                 # (T, held)
+        return _experts(self, x, comb, lp["moe/w_gate"], lp["moe/w_up"],
+                        lp["moe/w_down"])
+
+    def shared(self, lp, x):
+        """The shared experts, whole (None where the model has none)."""
+        if not self.fs:
+            return None
+        ys = self.swiglu(x, lp["moe/shared/w_gate"], lp["moe/shared/w_up"],
+                         lp["moe/shared/w_down"])
+        if not self.shared_gate:
+            return ys
+        return jax.nn.sigmoid(self.mm("td,do->to", x,
+                                      lp["moe/shared/gate"])) * ys
+
+    def moe(self, lp, x):
+        y = self.experts(lp, x, *self.route(lp, x))
+        ys = self.shared(lp, x)
+        return y if ys is None else y + ys
+
+    def ffn(self, kind, lp, y1):
         B, S, _ = y1.shape
         h = self.mm("bsh,hd->bsd", self.rms(y1, lp["norm_mlp"]),
                     lp["mlp_ad/p_up"])
         x = h.reshape(B * S, self.d)
-        if self.E:
-            logits = self.mm("td,de->te", x, lp["moe/router"])
-            probs = jax.nn.softmax(logits, axis=-1)
-            gv, gi = jax.lax.top_k(probs, self.top_k)
-            if self.norm_topk:
-                gv = gv / (jnp.sum(gv, -1, keepdims=True) + 1e-9)
-            comb = jnp.sum(jax.nn.one_hot(gi, self.E, dtype=F32)
-                           * gv[..., None], axis=1)               # (T, E)
-            y = _experts(self, x, comb, lp["moe/w_gate"], lp["moe/w_up"],
-                         lp["moe/w_down"])
-            if self.fs:
-                ys = self.swiglu(x, lp["moe/shared/w_gate"],
-                                 lp["moe/shared/w_up"],
-                                 lp["moe/shared/w_down"])
-                gate = jax.nn.sigmoid(self.mm("td,do->to", x,
-                                              lp["moe/shared/gate"]))
-                y = y + gate * ys
+        if kind == "moe":
+            y = self.moe(lp, x)
         else:
             y = self.swiglu(x, lp["mlp/w_gate"], lp["mlp/w_up"],
                             lp["mlp/w_down"])
         y = y.reshape(B, S, self.d)
         return self.mm("bsd,dh->bsh", y, lp["mlp_ad/p_down"])
 
-    def layer(self, lp, x1, x2):
-        y1 = x1 + self.attention(lp, x1, x2)
-        y2 = x2 + self.ffn(lp, y1)
+    def layer(self, kind, lp, x1, x2):
+        """One layer of a stack of ``kind``."""
+        y1 = x1 + self.attention(kind, lp, x1, x2)
+        y2 = x2 + self.ffn(kind, lp, y1)
         return y1, y2
 
     def tail(self, top, y1, y2, tokens, mask):
@@ -375,7 +446,6 @@ def _served_logits(ref: RevFFN, seed: int, seqs, pad_to: int):
     """Float32 logits of every position of each sequence (a (1, pad_to)
     row each, padded at the end, which causal attention ignores), computed
     layer by layer with each layer's weights drawn once."""
-    fwd = jax.jit(ref.layer)
     tops = {l.path[0]: l for l in ref.top_leaves()}
     emb = ref.draw_top(seed, tops["embed"])
     xs = []
@@ -385,11 +455,13 @@ def _served_logits(ref: RevFFN, seed: int, seqs, pad_to: int):
         h = emb[jnp.asarray(tok)]
         xs.append((h[..., :ref.half], h[..., ref.half:]))
     del emb
-    for i in range(ref.L):
-        lp = {ref.lname(l): ref.draw_layer(seed, l, i)
-              for l in ref.layer_leaves()}
-        xs = [fwd(lp, a, b) for a, b in xs]
-        del lp
+    for name, depth, kind in ref.stacks:
+        fwd = jax.jit(functools.partial(ref.layer, kind))
+        leaves = ref.leaves_of(name)
+        for i in range(depth):
+            lp = {ref.lname(l): ref.draw_layer(seed, l, i) for l in leaves}
+            xs = [fwd(lp, a, b) for a, b in xs]
+            del lp
     top = {k: ref.draw_top(seed, tops[k]) for k in ("final_norm", "lm_head")}
     head = jax.jit(ref.logits)
     return [head(top, a, b)[0] for a, b in xs]

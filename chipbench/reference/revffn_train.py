@@ -11,6 +11,8 @@ chip beside nothing else.
 """
 from __future__ import annotations
 
+import functools
+import json
 from typing import Dict, List, Optional
 
 import jax
@@ -36,18 +38,22 @@ def _ss(x):
 
 
 class _Jits:
-    """The reference's jitted pieces, built once per (model, mode)."""
+    """The reference's jitted pieces, built once per (model, mode): one
+    layer forward and one layer vjp per stack."""
 
     def __init__(self, ref: RevFFN):
         self.ref = ref
         self.shadow = jax.jit(lambda t: jax.tree_util.tree_map(_bf16, t))
         self.rows = jax.jit(lambda table, tok: _bf16(table[tok]))
-        self.fwd = jax.jit(ref.layer)
+        self.fwd, self.vjp = {}, {}
+        for name, _, kind in ref.stacks:
+            layer = functools.partial(ref.layer, kind)
 
-        def vjp_layer(lp, x1, x2, d1, d2):
-            _, f = jax.vjp(ref.layer, lp, x1, x2)
-            return f((d1, d2))
-        self.vjp = jax.jit(vjp_layer)
+            def vjp_layer(lp, x1, x2, d1, d2, layer=layer):
+                _, f = jax.vjp(layer, lp, x1, x2)
+                return f((d1, d2))
+            self.fwd[name] = jax.jit(layer)
+            self.vjp[name] = jax.jit(vjp_layer)
 
         def vjp_tail(top, y1, y2, tok, msk):
             loss, f = jax.vjp(lambda t, a, b: ref.tail(t, a, b, tok, msk),
@@ -64,7 +70,7 @@ _JITS: Dict = {}
 
 
 def _jits(ref: RevFFN) -> _Jits:
-    key = (id(type(ref)), ref.mode, ref.d, ref.L, ref.E, ref.V, ref.H)
+    key = (type(ref), ref.mode, json.dumps(ref.cfg, sort_keys=True))
     if key not in _JITS:
         _JITS[key] = _Jits(ref)
     return _JITS[key]
@@ -106,8 +112,9 @@ def run_steps(ref: RevFFN, opt: dict, seed: int, batches: List[dict],
     for LoMo, whose state keeps only the masked step) and unclipped
     (``raw_grad``), and ``change``: the norm of each leaf's move over all
     the steps.  Leaves are 'embed', 'final_norm', 'lm_head' and
-    'layers.<i>.<path>'.  ``fault="half"`` takes the loss over half of the
-    batch (half of the rows, or half of the positions of a single row)."""
+    '<stack>.<i>.<path>' (``RevFFN.reading``).  ``fault="half"`` takes the
+    loss over half of the batch (half of the rows, or half of the positions
+    of a single row)."""
     J = _jits(ref)
     lomo = opt["name"] == "lomo"
     frozen = tuple(opt.get("frozen", ()))
@@ -115,10 +122,14 @@ def run_steps(ref: RevFFN, opt: dict, seed: int, batches: List[dict],
     upd = _update_fn(opt)
     masked = lambda name: any(p in frozen for p in name.split("/"))
 
+    # every layer in the walk's order: (stack, index in the stack, leaves)
+    order = [(name, i, ref.leaves_of(name))
+             for name, depth, _ in ref.stacks for i in range(depth)]
+
     def fresh():
         top = {l.path[0]: ref.draw_top(seed, l) for l in ref.top_leaves()}
-        layers = [{ref.lname(l): ref.draw_layer(seed, l, i)
-                   for l in ref.layer_leaves()} for i in range(ref.L)]
+        layers = [{ref.lname(l): ref.draw_layer(seed, l, i) for l in leaves}
+                  for _, i, leaves in order]
         return top, layers
 
     top, layers = fresh()
@@ -146,9 +157,9 @@ def run_steps(ref: RevFFN, opt: dict, seed: int, batches: List[dict],
         h0 = J.rows(top["embed"], tok)
         x1, x2 = h0[..., :ref.half], h0[..., ref.half:]
         xs = []
-        for i in range(ref.L):
+        for j, (name, _, _) in enumerate(order):
             xs.append((x1, x2))
-            x1, x2 = J.fwd(weights(layers[i]), x1, x2)
+            x1, x2 = J.fwd[name](weights(layers[j]), x1, x2)
         tw = weights({"final_norm": top["final_norm"],
                       "lm_head": top["lm_head"]})
         loss, dtop, dy1, dy2 = J.tail(tw, x1, x2, tok, msk)
@@ -158,10 +169,11 @@ def run_steps(ref: RevFFN, opt: dict, seed: int, batches: List[dict],
         sq = {k: float(v) for k, v in J.sumsq(dtop).items()}
         del dtop
         d1, d2 = dy1, dy2
-        for i in reversed(range(ref.L)):
-            dlp, d1, d2 = J.vjp(weights(layers[i]), *xs[i], d1, d2)
+        for j in reversed(range(len(order))):
+            name, i, _ = order[j]
+            dlp, d1, d2 = J.vjp[name](weights(layers[j]), *xs[j], d1, d2)
             for k, v in J.sumsq(dlp).items():
-                sq[f"layers.{i}.{k}"] = float(v)
+                sq[ref.reading(name, i, k)] = float(v)
             del dlp
         dh0 = jnp.concatenate([d1, d2], -1)
         sq["embed"] = float(J.sumsq(J.embed_grad(dh0, tok)))
@@ -183,12 +195,13 @@ def run_steps(ref: RevFFN, opt: dict, seed: int, batches: List[dict],
             top[k], st_top[k] = upd(top[k], dtop[k], st_top[k], scale, mk, tf)
         del dtop
         d1, d2 = dy1, dy2
-        for i in reversed(range(ref.L)):
-            dlp, d1, d2 = J.vjp(weights(layers[i]), *xs[i], d1, d2)
-            for k in list(layers[i]):
+        for j in reversed(range(len(order))):
+            dlp, d1, d2 = J.vjp[order[j][0]](weights(layers[j]), *xs[j],
+                                             d1, d2)
+            for k in list(layers[j]):
                 mk = 0.0 if masked(k) else 1.0
-                layers[i][k], st_lay[i][k] = upd(layers[i][k], dlp[k],
-                                                 st_lay[i][k], scale, mk, tf)
+                layers[j][k], st_lay[j][k] = upd(layers[j][k], dlp[k],
+                                                 st_lay[j][k], scale, mk, tf)
             del dlp
         g = J.embed_grad(jnp.concatenate([d1, d2], -1), tok)
         top["embed"], st_top["embed"] = upd(top["embed"], g, st_top["embed"],
@@ -201,10 +214,10 @@ def run_steps(ref: RevFFN, opt: dict, seed: int, batches: List[dict],
     for l in ref.top_leaves():
         k = l.path[0]
         change[k] = float(jnp.sqrt(_ss(top[k] - ref.draw_top(seed, l))))
-    for i in range(ref.L):
-        for l in ref.layer_leaves():
+    for (name, i, leaves), lp in zip(order, layers):
+        for l in leaves:
             k = ref.lname(l)
-            change[f"layers.{i}.{k}"] = float(jnp.sqrt(_ss(
-                layers[i][k] - ref.draw_layer(seed, l, i))))
+            change[ref.reading(name, i, k)] = float(jnp.sqrt(_ss(
+                lp[k] - ref.draw_layer(seed, l, i))))
     out["change"] = change
     return out

@@ -58,7 +58,7 @@ def test_qwen_layer_hand_count():
     router = 2 * d * 60
     experts = 4 * 3 * 2 * d * 1408
     shared = 3 * 2 * d * 5632 + 2 * d
-    assert counts.layer_matmul_flops(cfg) == adapters + attn + router \
+    assert counts.layer_matmul_flops(cfg, "moe") == adapters + attn + router \
         + experts + shared
     # causal mean over 8192 positions: (8192 + 1) / 2 keys
     assert counts.attended_keys(8192) == 4096.5
@@ -73,7 +73,7 @@ def test_danube_layer_hand_count():
     adapters = 2 * (2 * half * d + d * half) + 2 * (half * d + d * half)
     attn = 2 * (d * 2560 + 2 * d * 640 + 2560 * d)     # 32 x 80, kv 8 x 80
     mlp = 3 * 2 * d * 6912
-    assert counts.layer_matmul_flops(cfg) == adapters + attn + mlp
+    assert counts.layer_matmul_flops(cfg, "dense") == adapters + attn + mlp
     # window 4096 covers a 4096 row: plain causal
     assert counts.attended_keys(4096, 4096) == 2048.5
     # a 6000-token row under the window: 4096 x 4097 / 2 + 1904 x 4096
@@ -84,5 +84,5 @@ def test_danube_layer_hand_count():
 def test_flash_and_gmm_counts():
     f, b = counts.gmm_call(rows=8, k=4, n=2, experts=3)
     assert f == 2 * 8 * 4 * 2 and b == 2 * (8 * 4 + 3 * 4 * 2 + 8 * 2)
-    f, _ = counts.flash_call("fwd", 1, 2, 2, 4, 8)
+    f, _ = counts.flash_call("fwd", 1, 2, 2, 4, 8, 8)
     assert f == 2 * 2 * 1 * 2 * 8 * (4 * 5 / 2)

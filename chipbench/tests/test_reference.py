@@ -39,11 +39,14 @@ def test_expert_backward_matches_autodiff():
         np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4)
 
 
-def test_leaves_drawn_again_equal_the_tree():
-    ref = revffn.RevFFN(json.load(open(DATA / "tiny-moe.json")))
+@pytest.mark.parametrize("data", ["tiny-moe", "tiny-dense-first"])
+def test_leaves_drawn_again_equal_the_tree(data):
+    """The program may hold more experts than the reference reads (here
+    32, as padding): the reference's are the first ones, bit for bit."""
+    ref = revffn.RevFFN(json.load(open(DATA / f"{data}.json")))
     seed = 2**34 + 9
-    counts = {l.path: ((ref.L, 32) if "experts" in l.stack else
-                       (ref.L,) if l.stack else ()) for l in ref.layout()}
+    counts = {l.path: (ref.depth(l),) + (32,) * (len(l.stack) - 1)
+              if l.stack else () for l in ref.layout()}
     tree = W.make_tree(seed, ref.layout(), counts, jnp.bfloat16)
     for leaf in ref.layout():
         got = tree
@@ -53,11 +56,12 @@ def test_leaves_drawn_again_equal_the_tree():
         if not leaf.stack:
             np.testing.assert_array_equal(got, ref.draw_top(seed, leaf))
             continue
-        for i in range(ref.L):
+        for i in range(ref.depth(leaf)):
             want = np.asarray(ref.draw_layer(seed, leaf, i))
             g = got[i]
-            if "experts" in leaf.stack:     # the program holds 32, uses 20
-                g = g[..., :ref.E] if leaf.experts_last else g[:ref.E]
+            if len(leaf.stack) > 1:
+                n = ref.count(leaf.stack[1])
+                g = g[..., :n] if leaf.experts_last else g[:n]
             np.testing.assert_array_equal(g, want)
 
 

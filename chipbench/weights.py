@@ -5,7 +5,8 @@ makes the program's whole tree in one jitted call, and the reference makes
 any one leaf, layer or expert again, bit for bit, without the program.
 
 A leaf of the layout is ``Leaf(path, stack, core, kind)``: ``stack`` names
-its leading axes ("layers", "experts"), ``core`` the shape of one slice, and
+its leading axes (its stack's "layers", then the experts held, "experts", or
+every published expert, "routed"), ``core`` the shape of one slice, and
 ``kind`` how it is drawn:
 
   embed    unit scale (rows of about unit RMS, as an embedding table)
@@ -34,7 +35,7 @@ class Leaf:
     stack: Tuple[str, ...]          # leading axes, in order
     core: Tuple[int, ...]
     kind: str
-    experts_last: bool = False      # the "experts" axis sits last in the
+    experts_last: bool = False      # the last stack axis sits last in the
     #                                 program's array (a router's columns)
 
 
@@ -98,8 +99,8 @@ def draw_stacked(key, leaf: Leaf, counts: Sequence[int], dtype,
         return jax.vmap(lambda i: one(jax.random.fold_in(k, i), depth + 1))(
             idx)
     out = one(key, 0)
-    if leaf.experts_last and "experts" in leaf.stack[len(index):]:
-        out = jnp.moveaxis(out, leaf.stack[len(index):].index("experts"), -1)
+    if leaf.experts_last and len(leaf.stack) > len(index):
+        out = jnp.moveaxis(out, len(leaf.stack) - len(index) - 1, -1)
     return out
 
 
